@@ -46,6 +46,7 @@ def _analytic_entry(plan, mesh_name: str) -> dict:
     tokens = plan.shape.global_batch * (
         plan.shape.seq_len if plan.kind != "decode" else 1)
     rt = hlo_analysis.RooflineTerms(
+        hw=hlo_analysis.TPU_V5E,  # the pod these cells model
         name=f"{plan.name}@{mesh_name}", chips=chips,
         hlo_flops=c.flops, hlo_bytes=c.hbm_bytes,
         collective_bytes=c.collective_bytes,

@@ -68,6 +68,7 @@ def main():
     cost = analytic.cell_cost(cfg, shape, kind="train", microbatches=2,
                               data_shards=16, model_shards=16)
     rt = hlo_analysis.RooflineTerms(
+        hw=hlo_analysis.TPU_V5E,  # the pod these cells model
         name="deepseek-moe train_4k", chips=256,
         hlo_flops=cost.flops, hlo_bytes=cost.hbm_bytes,
         collective_bytes=cost.collective_bytes,

@@ -24,11 +24,13 @@ Counting rules (DAMOV counts arithmetic operations, not instructions):
 - reductions (and cumulative ops) cost one op per *input* element.
 
 The counter is exact against the hand formulas for the stream / gather /
-MoE / SSM-ema capture hooks (16 of the 24 captured roster entries) and
-agrees within ~5% for flash-attention, paged-KV decode and SSM-expand,
-whose formulas round the softmax / chunk-mask epilogues to flat
-per-score constants (``tests/test_capture_model.py`` pins both claims on
-all 24 entries).
+MoE capture hooks (14 of the 24 captured roster entries) and agrees
+within ~5% for flash-attention, paged-KV decode and the SSM scans, whose
+formulas round the softmax / chunk-mask epilogues to flat per-score
+constants.  The SSM kernels' prefix sums run as lower-triangular MXU
+matmuls, which their formulas (the recurrence's own arithmetic) leave
+out; ``tests/test_capture_model.py`` adds those back and pins all 24
+entries.
 """
 
 from __future__ import annotations

@@ -229,7 +229,7 @@ def _np_index_table(jaxpr, consts, grid: tuple[int, ...], scalars,
     the interpreter: the jax evaluation XLA-compiles one vmapped
     program per (operand, grid) shape, which dominates cold suite builds.
     """
-    from jax import core
+    from jax.extend.core import Literal
 
     n_steps = 1
     for g in grid:
@@ -238,7 +238,7 @@ def _np_index_table(jaxpr, consts, grid: tuple[int, ...], scalars,
     env: dict = {}
 
     def read(v):
-        if isinstance(v, core.Literal):
+        if isinstance(v, Literal):
             return (np.asarray(v.val), False)
         return env[v]
 
@@ -436,6 +436,24 @@ def from_jaxpr(fn, args: Sequence, *, scalar_values: Sequence = (),
                               flops=flops, name=name)
 
 
+def block_dim_size(dim) -> int:
+    """Elements one block spans along one dim of a ``BlockMapping``.
+
+    ``Blocked(n)`` spans ``n``; ``Squeezed`` (what a ``None`` block dim
+    becomes) spans one element.  ``Element`` and ``BoundedSlice`` index
+    maps return element offsets rather than block indices, which the
+    walker does not model, so they are refused.
+    """
+    from jax.experimental import pallas as pl
+
+    if dim is None or isinstance(dim, pl.Squeezed):
+        return 1
+    if isinstance(dim, pl.Blocked):
+        return int(dim.block_size)
+    raise NotImplementedError(
+        f"block dim {dim!r}: only Blocked and Squeezed dims are captured")
+
+
 def capture_pallas_eqn(eqn, *, scalar_values: Sequence = (),
                        flops: float | None = None,
                        name: str | None = None) -> GridCapture:
@@ -473,8 +491,7 @@ def capture_pallas_eqn(eqn, *, scalar_values: Sequence = (),
             f"count {len(block_mapped)}")
     scalars = tuple(np.asarray(v) for v in scalar_values)
     for (op_name, role, sds), bm in zip(block_mapped, mappings):
-        block_shape = tuple(
-            1 if b is None else int(b) for b in bm.block_shape)
+        block_shape = tuple(block_dim_size(b) for b in bm.block_shape)
         table = _tabulate_index_map(bm.index_map_jaxpr, grid, scalars)
         if table.shape[1] != len(block_shape):
             raise ValueError(

@@ -56,8 +56,8 @@ def default_backend() -> str:
     counter-identical to the reference loop (see
     ``tests/test_cachesim_vec.py``) and 10-40x faster.  ``jax`` is the
     vectorized backend with the contested-revisit window scan jitted as
-    ``jax.numpy`` ops (counter-identical; falls back to the NumPy scan
-    with a one-time warning when jax is absent).
+    ``jax.numpy`` ops (counter-identical; without jax it raises rather
+    than run the NumPy scan).
     """
     backend = os.environ.get("REPRO_SIM_BACKEND", "vectorized")
     if backend not in BACKENDS:

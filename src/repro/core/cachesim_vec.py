@@ -79,9 +79,9 @@ gather-compare-reduce — exactly the shape accelerators like.  Under
 window count runs as jitted ``jax.numpy`` ops: the set-major ``q`` array
 is placed on device once per scan, row counts are padded to powers of two
 so the geometric chunk growth compiles O(log) kernels, and arithmetic is
-int32 (guarded: streams >= 2^28 collapsed refs fall back to NumPy).  When
-jax is absent the selector warns once and uses the NumPy path — counters
-are identical either way, which the differential gate asserts.
+int32 (streams of 2^28 or more collapsed refs raise).  The jax scan never
+falls back to NumPy: without jax it raises.  Counters are identical to
+the NumPy scan, which the differential gate asserts.
 
 The stream prefetcher is inherently sequential (its issue decisions feed
 back through L2 residency and a bounded ``prefetched`` set with arbitrary
@@ -300,26 +300,19 @@ def _replay_ways(
 # --------------------------------------------------------------------------
 # jax window-count kernel: the inner gather-compare-reduce of the scan.
 # --------------------------------------------------------------------------
-_JAX_SCAN: list = []   # lazy singleton: [(jax, jitted kernel)] or [None]
+_JAX_SCAN: list = []   # lazy singleton: [(jax, jitted kernel)]
+_JAX_SHAPES: set = set()   # (stream, padded rows, chunk) shapes compiled
 _JAX_MAX_M = 1 << 28   # int32 headroom: lo + chunk stays < 2^31
 
 
 def _jax_window_kernel():
-    """The jitted (rows x chunk) window-count kernel, or ``None`` when jax
-    is unavailable (warned once; callers fall back to NumPy)."""
+    """The jitted (rows x chunk) window-count kernel.  jax is required:
+    a failed import raises rather than running the NumPy scan."""
     if not _JAX_SCAN:
-        try:
-            import functools
+        import functools
 
-            import jax
-            import jax.numpy as jnp
-        except Exception as exc:  # pragma: no cover - env without jax
-            obs.warn_once(
-                "jax-scan",
-                f"scan backend 'jax' unavailable ({exc!r}); "
-                "falling back to the NumPy window scan")
-            _JAX_SCAN.append(None)
-            return None
+        import jax
+        import jax.numpy as jnp
 
         @functools.partial(jax.jit, static_argnames=("chunk",))
         def kern(q, lo, thr, span, chunk):
@@ -348,6 +341,10 @@ def _jax_window_counts(kern, q_dev, lo, thr, span, chunk) -> np.ndarray:
     lo32[:rows] = lo
     thr32[:rows] = thr
     span32[:rows] = span
+    shape = (int(q_dev.shape[0]), padded, int(chunk))
+    if shape not in _JAX_SHAPES:
+        _JAX_SHAPES.add(shape)
+        obs.count("scan.jax.programs")
     out = kern(q_dev, lo32, thr32, span32, int(chunk))
     return np.asarray(out)[:rows].astype(np.int64)
 
@@ -372,8 +369,8 @@ def _contested_sd(cl, sidx, prev, queries, sets, cap, skip_below,
     window-first exactly as they should.
 
     ``scan="jax"`` runs the per-chunk gather-compare-reduce as jitted
-    ``jax.numpy`` ops (NumPy fallback when jax is absent or the stream
-    exceeds the int32 guard); counts are identical either way.
+    ``jax.numpy`` ops; counts are identical to the NumPy scan.  It never
+    falls back to NumPy: a stream past the int32 guard raises.
     """
     m = int(cl.size)
     if sets <= (1 << 8):
@@ -410,9 +407,12 @@ def _contested_sd(cl, sidx, prev, queries, sets, cap, skip_below,
     live = np.flatnonzero(win_hi - win_lo >= skip_below)
 
     jx = None
-    if scan == "jax" and m < _JAX_MAX_M:
+    if scan == "jax":
+        if m >= _JAX_MAX_M:
+            raise ValueError(
+                f"scan='jax' takes streams below {_JAX_MAX_M} collapsed "
+                f"refs (int32 window offsets); got {m}")
         jx = _jax_window_kernel()
-    if jx is not None:
         jax_mod, kern = jx
         obs.count("scan.jax")
         q_dev = jax_mod.device_put(q.astype(np.int32))
@@ -674,6 +674,16 @@ def _memo_for(addr: np.ndarray) -> _TraceMemo:
         obs.count("memo.bytes", total - _MEMO_BYTES_LAST)
         _MEMO_BYTES_LAST = total
         return found
+
+
+def clear_memo() -> None:
+    """Drop every trace memo, so the next simulation of any trace runs
+    every level again (on whichever scan backend it asks for)."""
+    global _MEMO_BYTES_LAST
+    with _MEMOS_LOCK:
+        _MEMOS.clear()
+        obs.count("memo.bytes", -_MEMO_BYTES_LAST)
+        _MEMO_BYTES_LAST = 0
 
 
 def _pf_l2_replay(stream, l2_nsets: int, l2_ways: int,

@@ -36,11 +36,12 @@ from dataclasses import dataclass, field
 
 __all__ = [
     "TPU_V5E",
+    "PEAKS",
+    "device_spec",
     "HardwareSpec",
     "CollectiveStats",
     "RooflineTerms",
     "collective_stats",
-    "roofline",
     "dtype_bytes",
 ]
 
@@ -55,14 +56,34 @@ class HardwareSpec:
     dispatch_latency_s: float = 3e-6   # per executed HLO "step" floor
 
 
-# Hardware constants given for this assignment: 197 TFLOP/s bf16,
-# 819 GB/s HBM, ~50 GB/s/link ICI.
+# Published per-chip peaks of one TPU v5e (Google Cloud documentation,
+# "TPU v5e"): 197 TFLOP/s bf16, 16 GB of HBM at 819 GB/s, 1,600 Gbit/s of
+# chip-to-chip interconnect (~50 GB/s per link).
 TPU_V5E = HardwareSpec(
     name="tpu_v5e",
     peak_flops=197e12,
     hbm_bw=819e9,
     ici_bw=50e9,
 )
+
+# The peak table, keyed by jax's ``Device.device_kind``.
+PEAKS: dict[str, HardwareSpec] = {"TPU v5 lite": TPU_V5E}
+
+
+def device_spec(kind: str | None = None) -> HardwareSpec:
+    """Peaks of the chip ``kind`` names (default: the device jax runs on,
+    ``jax.devices()[0].device_kind``).  A kind missing from :data:`PEAKS`
+    is an error: no peak is ever assumed."""
+    if kind is None:
+        import jax
+
+        kind = jax.devices()[0].device_kind
+    try:
+        return PEAKS[kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak table entry for device kind {kind!r}; known kinds: "
+            f"{sorted(PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1,
@@ -152,7 +173,8 @@ class RooflineTerms:
     hlo_bytes: float
     collective_bytes: float
     model_flops: float = 0.0
-    hw: HardwareSpec = TPU_V5E
+    # the chip the terms are taken against; default: the device jax runs on
+    hw: HardwareSpec = field(default_factory=device_spec)
     n_ops: int = 0
 
     # ---- the three terms, in seconds ------------------------------------
@@ -237,33 +259,3 @@ class RooflineTerms:
             "mfu_bound": self.mfu_bound,
             "roofline_fraction": self.roofline_fraction,
         }
-
-
-def roofline(
-    name: str,
-    *,
-    chips: int,
-    cost_analysis: dict[str, float] | None,
-    hlo_text: str,
-    model_flops: float = 0.0,
-    hw: HardwareSpec = TPU_V5E,
-) -> RooflineTerms:
-    """Build roofline terms from a compiled dry-run artifact."""
-    ca = cost_analysis or {}
-    flops = float(ca.get("flops", 0.0))
-    nbytes = float(ca.get("bytes accessed", 0.0))
-    coll = collective_stats(hlo_text)
-    n_ops = sum(
-        1 for ln in hlo_text.splitlines()
-        if re.search(r"=\s*[a-z0-9]+\[", ln) and "parameter(" not in ln
-    )
-    return RooflineTerms(
-        name=name,
-        chips=chips,
-        hlo_flops=flops,
-        hlo_bytes=nbytes,
-        collective_bytes=float(coll.total_bytes),
-        model_flops=model_flops,
-        hw=hw,
-        n_ops=n_ops,
-    )

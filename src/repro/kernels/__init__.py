@@ -1,10 +1,10 @@
 """Pallas TPU kernels for the framework's compute hot-spots.
 
 Each kernel package has ``kernel.py`` (pl.pallas_call + BlockSpec VMEM
-tiling), ``ops.py`` (jitted public wrapper with CPU fallback), ``ref.py``
-(pure-jnp oracle used by the allclose test sweeps) and ``capture.py`` (the
-per-thread trace-capture hook feeding the benchmark suite — see
-``docs/adding-a-kernel.md``):
+tiling; ``interpret=True`` runs it in interpret mode, as the CPU tests do),
+``ref.py`` (pure-jnp oracle used by the allclose test sweeps) and
+``capture.py`` (the per-thread trace-capture hook feeding the benchmark
+suite — see ``docs/adding-a-kernel.md``):
 
 - ``flash_attention`` — online-softmax attention (the LM hot-spot; never
   materializes [S, S] scores in HBM; causal tiles skipped).

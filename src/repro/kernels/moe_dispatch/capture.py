@@ -90,7 +90,8 @@ def _traced(n_tokens: int, d: int, f: int, n_experts: int,
 
 def _mirror(n_tokens: int, d: int, f: int, n_experts: int,
             tok: np.ndarray, eid: np.ndarray, flops: float) -> GridCapture:
-    """Jax-free fallback: the launch geometry as plain data."""
+    """Jax-free fallback: the launch geometry as plain data (activation
+    and output rows through the kernel's ``[tokens, 1, width]`` views)."""
 
     def prefetch(name: str) -> OperandSpec:
         return OperandSpec(
@@ -106,9 +107,9 @@ def _mirror(n_tokens: int, d: int, f: int, n_experts: int,
             prefetch("tok"),
             prefetch("eid"),
             OperandSpec(
-                name="x", role="in", shape=(n_tokens, d),
-                block_shape=(1, d),
-                index_map=lambda i, _t=tok: (int(_t[i]), 0),
+                name="x", role="in", shape=(n_tokens, 1, d),
+                block_shape=(1, 1, d),
+                index_map=lambda i, _t=tok: (int(_t[i]), 0, 0),
             ),
             OperandSpec(
                 name="w", role="in", shape=(n_experts, d, f),
@@ -116,9 +117,9 @@ def _mirror(n_tokens: int, d: int, f: int, n_experts: int,
                 index_map=lambda i, _e=eid: (int(_e[i]), 0, 0),
             ),
             OperandSpec(
-                name="y", role="out", shape=(n_tokens, f),
-                block_shape=(1, f),
-                index_map=lambda i, _t=tok: (int(_t[i]), 0),
+                name="y", role="out", shape=(n_tokens, 1, f),
+                block_shape=(1, 1, f),
+                index_map=lambda i, _t=tok: (int(_t[i]), 0, 0),
             ),
         ),
         flops=flops,

@@ -15,7 +15,11 @@ step ``i`` the BlockSpec index maps steer three DMAs:
   and the capture path reproduces it exactly;
 - ``y[tok[i]]``   — scatter the FFN output row back to token order.
 
-The kernel body is just the per-token expert GEMM ``y = x @ w``.
+The kernel body is just the per-token expert GEMM ``y = x @ w``.  The
+activation and output rows are viewed as ``[T, 1, D]`` / ``[T, 1, F]``
+with ``(None, 1, ·)`` blocks, so each block's last two dims equal the
+array's and meet the TPU's (8, 128) tiling rule; the views are free
+reshapes and leave the DMA word stream unchanged.
 """
 
 from __future__ import annotations
@@ -52,17 +56,19 @@ def moe_dispatch_sorted(x, w, tok, eid, *, interpret: bool = False):
         num_scalar_prefetch=2,
         grid=(t,),
         in_specs=[
-            pl.BlockSpec((1, d), lambda i, tok, eid: (tok[i], 0)),
+            pl.BlockSpec((None, 1, d), lambda i, tok, eid: (tok[i], 0, 0)),
             pl.BlockSpec((1, d, f), lambda i, tok, eid: (eid[i], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, f), lambda i, tok, eid: (tok[i], 0)),
+        out_specs=pl.BlockSpec((None, 1, f),
+                               lambda i, tok, eid: (tok[i], 0, 0)),
     )
-    return pl.pallas_call(
+    y = pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((t, f), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((t, 1, f), x.dtype),
         interpret=interpret,
-    )(tok.astype(jnp.int32), eid.astype(jnp.int32), x, w)
+    )(tok.astype(jnp.int32), eid.astype(jnp.int32), x.reshape(t, 1, d), w)
+    return y.reshape(t, f)
 
 
 def moe_dispatch(x, w, expert_ids, *, interpret: bool = False):

@@ -29,7 +29,7 @@ def scan_flops(op: str, *, seq_len: int, d: int, n: int, chunk: int) -> float:
     """Arithmetic ops of one scan over ``seq_len`` steps."""
     n_chunks = seq_len // chunk
     if op == "ema":
-        # cumprod + div + cumsum + state mul/add + gate, per element
+        # decay product + div + running sum + state mul/add + gate
         return 6.0 * seq_len * d
     # chunk closed form: gram [C,C,N] + masked matmul [C,C,D] + two
     # state contractions [C,N,D] + the vector epilogue
@@ -48,10 +48,13 @@ def capture(op: str, *, seq_len: int, d: int, n: int = 128,
     if d % 128:
         raise ValueError(f"d {d} must be a multiple of 128 (lane dim)")
     t_thread = max(chunk, seq_len // max(1, cores) // chunk * chunk)
-    # Kept on both capture paths (the mirror has no jaxpr to count): the
-    # jaxpr counter reproduces the ema formula exactly and the expand
-    # closed form within ~0.5% (it folds the chunk-boundary mask ops into
-    # 5*C*d) — pinned by tests/test_capture_model.py.
+    # Kept on both capture paths (the mirror has no jaxpr to count).  The
+    # formula is the recurrence's own arithmetic: the kernel's prefix
+    # sums run as tril(1) @ v MXU matmuls (2*C*C*d each per chunk), which
+    # it leaves out.  With those added, the jaxpr counter reproduces the
+    # ema formula exactly and the expand closed form within ~0.5% (it
+    # folds the chunk-boundary mask ops into 5*C*d) — pinned by
+    # tests/test_capture_model.py.
     flops = scan_flops(op, seq_len=t_thread, d=d, n=n, chunk=chunk)
     if capture_path(path) == "jaxpr":
         return memoized(

@@ -21,6 +21,13 @@ lives in VMEM scratch across the whole grid (it never touches HBM):
   closed form divides by the decay product, so extremely small per-chunk
   products (dt << 0.9 with large chunks) lose precision — callers pick
   the chunk length accordingly.
+
+Both kernels take the running sums along time the same MXU way: Mosaic
+lowers neither ``cumsum`` nor ``cumprod``, so a prefix sum is a matmul
+with the lower-triangular ones matrix, ``tril(1) @ v``, and the decay
+product is ``P = exp(tril(1) @ log(dt))``.  These matmuls run at full f32
+precision; they add ``2 * C * C * D`` operations per chunk each, which
+the captured FLOP formula (the recurrence's own arithmetic) leaves out.
 """
 
 from __future__ import annotations
@@ -35,6 +42,19 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["ssm_ema_scan", "ssm_chunked_scan"]
 
 
+def _prefix_sum(v):
+    """Running sum of ``v`` [C, D] along axis 0, as one MXU matmul."""
+    c = v.shape[0]
+    tril = jnp.tril(jnp.ones((c, c), jnp.float32))
+    return jax.lax.dot(tril, v, precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
+
+
+def _decay_product(dt):
+    """Running product of ``dt`` [C, D] in (0, 1] along axis 0."""
+    return jnp.exp(_prefix_sum(jnp.log(dt.astype(jnp.float32))))
+
+
 def _ema_kernel(x_ref, dt_ref, g_ref, y_ref, h_scr):
     i = pl.program_id(0)
 
@@ -42,8 +62,8 @@ def _ema_kernel(x_ref, dt_ref, g_ref, y_ref, h_scr):
     def _init():
         h_scr[...] = jnp.zeros_like(h_scr)
 
-    p = jnp.cumprod(dt_ref[...].astype(jnp.float32), axis=0)    # [C, D]
-    z = jnp.cumsum(x_ref[...].astype(jnp.float32) / p, axis=0)
+    p = _decay_product(dt_ref[...])                             # [C, D]
+    z = _prefix_sum(x_ref[...].astype(jnp.float32) / p)
     h = p * (h_scr[...] + z)                                    # [C, D]
     y_ref[...] = (g_ref[...].astype(jnp.float32) * h).astype(y_ref.dtype)
     h_scr[...] = h[-1:]
@@ -74,7 +94,7 @@ def _chunked_kernel(x_ref, dt_ref, b_ref, c_ref, y_ref, h_scr):
     def _init():
         h_scr[...] = jnp.zeros_like(h_scr)
 
-    p = jnp.cumprod(dt_ref[...].astype(jnp.float32), axis=0)    # [C, D]
+    p = _decay_product(dt_ref[...])                             # [C, D]
     xb = x_ref[...].astype(jnp.float32) / p                     # [C, D]
     bc = b_ref[...].astype(jnp.float32)                         # [C, N]
     cc = c_ref[...].astype(jnp.float32)                         # [C, N]
@@ -90,7 +110,7 @@ def _chunked_kernel(x_ref, dt_ref, b_ref, c_ref, y_ref, h_scr):
             cc, h0, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32))
     y_ref[...] = y.astype(y_ref.dtype)
-    h_scr[...] = p[-1] * (h0 + jax.lax.dot_general(
+    h_scr[...] = p[-1:] * (h0 + jax.lax.dot_general(
         bc, xb, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32))
 

@@ -16,7 +16,8 @@ from __future__ import annotations
 from repro.capture.grid import GridCapture, OperandSpec
 from repro.capture.jaxpr import capture_path, from_jaxpr, memoized
 
-__all__ = ["capture", "STREAM_OPS", "LANES", "DEFAULT_BLOCK_ROWS"]
+__all__ = ["capture", "bytes_moved", "STREAM_OPS", "LANES",
+           "DEFAULT_BLOCK_ROWS"]
 
 # Mirrors repro.kernels.stream.kernel (kept jax-free on purpose).
 LANES = 128
@@ -29,6 +30,12 @@ STREAM_OPS: dict[str, tuple[tuple[str, ...], float]] = {
     "add": (("a", "b"), 1.0),
     "triad": (("q", "a", "b"), 2.0),
 }
+
+
+def bytes_moved(op: str, n_elems: int, itemsize: int) -> int:
+    """HBM bytes per invocation (reads + writes), STREAM convention."""
+    arrays = sum(1 for name in STREAM_OPS[op][0] if name != "q")
+    return (arrays + 1) * n_elems * itemsize
 
 
 def capture(op: str, n_elems: int, *, cores: int = 1,

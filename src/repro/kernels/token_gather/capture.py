@@ -57,7 +57,8 @@ def _traced(n_rows: int, d: int, m: int, idx: np.ndarray) -> GridCapture:
 def _mirror(n_rows: int, d: int, m: int, idx: np.ndarray) -> GridCapture:
     """Jax-free fallback: the launch geometry as plain data — idx is
     scalar-prefetched once (constant index map), then each grid step ``i``
-    DMAs row block ``table[idx[i]]`` in and output row ``i`` out."""
+    DMAs row ``table[idx[i]]`` in and output row ``i`` out, both through
+    the kernel's ``[rows, 1, d]`` views."""
     return GridCapture(
         name="token_gather",
         grid=(m,),
@@ -71,13 +72,13 @@ def _mirror(n_rows: int, d: int, m: int, idx: np.ndarray) -> GridCapture:
                 elems_per_word=elems_per_word(np.int32, m),
             ),
             OperandSpec(
-                name="table", role="in", shape=(n_rows, d),
-                block_shape=(1, d),
-                index_map=lambda i, _idx=idx: (int(_idx[i]), 0),
+                name="table", role="in", shape=(n_rows, 1, d),
+                block_shape=(1, 1, d),
+                index_map=lambda i, _idx=idx: (int(_idx[i]), 0, 0),
             ),
             OperandSpec(
-                name="out", role="out", shape=(m, d), block_shape=(1, d),
-                index_map=lambda i: (i, 0),
+                name="out", role="out", shape=(m, 1, d),
+                block_shape=(1, 1, d), index_map=lambda i: (i, 0, 0),
             ),
         ),
         flops=0.0,  # pure data movement

@@ -11,8 +11,11 @@ rows are >= one VMEM tile — exactly the "extract MLP with regular engines"
 adaptation DAMOV §3.3.1 calls for (MoE token dispatch and paged-KV reads
 are this kernel).
 
-Rows are gathered at [rows_per_block, D] granularity; indices index whole
-row-blocks.
+Rows are gathered one at a time.  The table is viewed as ``[N, 1, D]``
+with ``(None, 1, D)`` blocks, so each block's last two dims equal the
+array's and meet the TPU's (8, 128) tiling rule; a ``(1, D)`` block of an
+``[N, D]`` table does not.  The view is a free reshape and leaves the
+DMA word stream unchanged.
 """
 
 from __future__ import annotations
@@ -46,13 +49,15 @@ def gather_rows(table, idx, *, interpret: bool = False):
         num_scalar_prefetch=1,
         grid=(m,),
         in_specs=[
-            pl.BlockSpec((1, d), lambda i, idx_ref: (idx_ref[i], 0)),
+            pl.BlockSpec((None, 1, d),
+                         lambda i, idx_ref: (idx_ref[i], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, d), lambda i, idx_ref: (i, 0)),
+        out_specs=pl.BlockSpec((None, 1, d), lambda i, idx_ref: (i, 0, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((m, d), table.dtype),
+        out_shape=jax.ShapeDtypeStruct((m, 1, d), table.dtype),
         interpret=interpret,
-    )(idx.astype(jnp.int32), table)
+    )(idx.astype(jnp.int32), table.reshape(n, 1, d))
+    return out.reshape(m, d)

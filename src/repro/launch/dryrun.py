@@ -104,6 +104,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
         infer_fsdp=plan.infer_fsdp,
     )
     rt = hlo_analysis.RooflineTerms(
+        hw=hlo_analysis.TPU_V5E,  # the pod these cells model
         name=f"{plan.name}@{'2pod' if multi_pod else '1pod'}",
         chips=chips,
         hlo_flops=costs.flops,

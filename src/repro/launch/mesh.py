@@ -17,21 +17,10 @@ __all__ = ["make_production_mesh", "make_local_mesh", "make_abstract_mesh"]
 
 
 def make_abstract_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Device-free mesh for resolving shardings (tests, planning).
-
-    ``jax.sharding.AbstractMesh`` changed its constructor across jax
-    releases: older releases take one ``((name, size), ...)`` shape tuple,
-    newer ones take ``(axis_sizes, axis_names)``.  Passing the wrong form
-    builds a mesh with a malformed shape tuple that explodes inside
-    ``jax._src.mesh`` (``TypeError: 'int' object is not iterable``), so
-    this is the one sanctioned constructor for abstract meshes here.
-    """
+    """Device-free mesh for resolving shardings (tests, planning)."""
     from jax.sharding import AbstractMesh
 
-    try:
-        return AbstractMesh(tuple(zip(axes, shape)))
-    except TypeError:
-        return AbstractMesh(tuple(shape), tuple(axes))
+    return AbstractMesh(tuple(shape), tuple(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

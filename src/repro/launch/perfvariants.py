@@ -117,6 +117,7 @@ def run_variant(tag, arch, shape, *, rules_override=(), microbatches=None,
     tokens = plan.shape.global_batch * (
         plan.shape.seq_len if plan.kind != "decode" else 1)
     rt = hlo_analysis.RooflineTerms(
+        hw=hlo_analysis.TPU_V5E,  # the pod these cells model
         name=tag, chips=chips, hlo_flops=costs.flops,
         hlo_bytes=costs.hbm_bytes, collective_bytes=costs.collective_bytes,
         model_flops=plan.cfg.model_flops(tokens,

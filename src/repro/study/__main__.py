@@ -160,4 +160,7 @@ def _main(args: argparse.Namespace) -> int:
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+
+    compile_cache.enable()
     raise SystemExit(main())

@@ -235,4 +235,7 @@ def _main(args: argparse.Namespace, refs: int) -> int:
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+
+    compile_cache.enable()
     raise SystemExit(main())

@@ -30,13 +30,18 @@ pickle boundary; instead each worker rebuilds the
 ``refs`` marker (cached per process) and characterizes entries by name.
 Rows computed in workers are identical to in-process rows (the pipeline
 is deterministic), and the parent persists them to the store exactly as
-in the sequential path.
+in the sequential path.  Workers are spawned with ``JAX_PLATFORMS=cpu``:
+they capture and simulate on the host and never claim the accelerator,
+which belongs to one process.  The ``jax`` backend runs its scan on that
+accelerator, so it cannot fan out and ``processes > 1`` with it raises.
 """
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import multiprocessing
+import os
 import time
 from concurrent.futures import ProcessPoolExecutor
 from dataclasses import dataclass
@@ -96,6 +101,22 @@ class RunStats:
 
     def as_dict(self) -> dict[str, int]:
         return {"computed": self.computed, "recalled": self.recalled}
+
+
+@contextlib.contextmanager
+def _env(**overrides: str):
+    """Set environment variables for the duration of the block (so every
+    process spawned inside it inherits them), then restore them."""
+    saved = {k: os.environ.get(k) for k in overrides}
+    os.environ.update(overrides)
+    try:
+        yield
+    finally:
+        for k, v in saved.items():
+            if v is None:
+                os.environ.pop(k, None)
+            else:
+                os.environ[k] = v
 
 
 @functools.lru_cache(maxsize=1)
@@ -350,8 +371,12 @@ class SuiteRunner:
         """
         processes = self.processes if processes is None else processes
         if processes == 0:
-            import os
             processes = os.cpu_count() or 1
+        if (processes or 1) > 1 and self.backend == "jax":
+            raise ValueError(
+                "backend 'jax' runs the window scan on the accelerator, "
+                "which one process holds at a time; run with processes=1 "
+                "(--processes 1)")
         todo = [
             e for e in self.registry
             if e.name not in self._rows and self._recall(e) is None
@@ -384,11 +409,15 @@ class SuiteRunner:
             # multithreaded library) loaded, and forking a multithreaded
             # process can deadlock a child on an inherited lock.  Workers
             # rebuild everything from the pickled task tuple anyway.
+            # Workers do host-only capture and NumPy simulation, so they
+            # are spawned with JAX_PLATFORMS=cpu: none of them may claim
+            # the accelerator the parent (or another worker) holds.
             ctx = multiprocessing.get_context("spawn")
             n_workers = min(processes, len(remote))
             t0 = time.perf_counter()
             with obs.span("suite.pool", entries=len(remote),
                           processes=n_workers), \
+                    _env(JAX_PLATFORMS="cpu"), \
                     ProcessPoolExecutor(max_workers=n_workers,
                                         mp_context=ctx) as pool:
                 for entry, row in zip(remote,

@@ -213,6 +213,46 @@ class TestJaxScan:
         for ps, js in zip(plain, jaxed):
             assert [_counters(s) for s in ps] == [_counters(s) for s in js]
 
+    def test_stream_past_int32_guard_raises(self, monkeypatch):
+        """A stream too long for int32 window offsets is refused, never
+        handed to the NumPy scan in silence."""
+        pytest.importorskip("jax")
+        monkeypatch.setattr(cachesim_vec, "_JAX_MAX_M", 16)
+        addr = _FAMILY_WORKLOADS["contended"].trace(4).addresses
+        with pytest.raises(ValueError, match="scan='jax'"):
+            cachesim.simulate(addr.copy(), cachesim.host_config(4),
+                              backend="jax")
+
+    def test_failed_jax_import_raises(self, monkeypatch):
+        """Without jax the jax scan raises instead of running NumPy."""
+        import sys
+
+        monkeypatch.setattr(cachesim_vec, "_JAX_SCAN", [])
+        monkeypatch.setitem(sys.modules, "jax", None)
+        addr = _FAMILY_WORKLOADS["contended"].trace(4).addresses
+        with pytest.raises(ImportError):
+            cachesim.simulate(addr.copy(), cachesim.host_config(4),
+                              backend="jax")
+
+    def test_clear_memo_forces_a_fresh_scan(self):
+        """After clear_memo the same trace array is profiled again (so a
+        second backend's run is its own), and the memo.bytes gauge
+        returns to zero."""
+        addr = _FAMILY_WORKLOADS["contended"].trace(4).addresses.copy()
+        cfg = cachesim.host_config(4)
+        cachesim_vec.clear_memo()
+        obs.reset_counters()
+        want = cachesim.simulate(addr, cfg, backend="vectorized")
+        cachesim.simulate(addr, cfg, backend="vectorized")
+        assert obs.counters().get("memo.hit", 0) >= 1
+        cachesim_vec.clear_memo()
+        assert obs.counters().get("memo.bytes", 0) == 0
+        obs.reset_counters()
+        got = cachesim.simulate(addr, cfg, backend="vectorized")
+        assert obs.counters().get("memo.miss", 0) == 1
+        assert obs.counters().get("memo.hit", 0) == 0
+        assert _counters(got) == _counters(want)
+
 
 # --------------------------------------------------------------------------
 # Megaref traces: fixed memory over 10M+ refs

@@ -220,6 +220,57 @@ class TestFromJaxpr:
         assert ia != ib
 
 
+# --------------------------------------------------------------------------
+# The one supported jax and its block-dim API.
+# --------------------------------------------------------------------------
+class TestBlockDims:
+    def test_installed_jax_is_0_9(self):
+        """The capture reads jax 0.9's BlockMapping dims; other releases
+        put other objects there (plain ints or ``None`` before 0.9)."""
+        assert jax.__version__.startswith("0.9."), jax.__version__
+
+    @pytest.mark.parametrize("dim, size", [
+        ("blocked", 8), ("squeezed", 1), ("none", 1)])
+    def test_block_dim_size(self, dim, size):
+        from jax.experimental import pallas as pl
+
+        from repro.capture.jaxpr import block_dim_size
+
+        obj = {"blocked": pl.Blocked(8), "squeezed": pl.Squeezed(),
+               "none": None}[dim]
+        assert block_dim_size(obj) == size
+
+    def test_element_dims_are_refused(self):
+        from jax.experimental import pallas as pl
+
+        from repro.capture.jaxpr import block_dim_size
+
+        with pytest.raises(NotImplementedError, match="Element"):
+            block_dim_size(pl.Element(8))
+
+    def test_none_block_dim_captures_as_one(self):
+        """A ``None`` block dim reaches the BlockMapping as ``Squeezed``
+        and captures as a one-element dim."""
+        import jax.numpy as jnp
+        from jax.experimental import pallas as pl
+
+        from repro.capture import from_jaxpr
+
+        def k(a_ref, o_ref):
+            o_ref[...] = a_ref[...]
+
+        def rows(a):
+            spec = pl.BlockSpec((None, 8, 128), lambda i: (i, 0, 0))
+            return pl.pallas_call(
+                k, grid=(a.shape[0],), in_specs=[spec], out_specs=spec,
+                out_shape=jax.ShapeDtypeStruct(a.shape, a.dtype))(a)
+
+        cap = from_jaxpr(rows, (jax.ShapeDtypeStruct((4, 8, 128),
+                                                     jnp.float32),))
+        assert [op.block_shape for op in cap.operands] == [(1, 8, 128)] * 2
+        assert walk(cap).loads == 4 * 8 * 128 // 2
+
+
 def test_default_path_is_jaxpr_with_jax_present():
     """With jax importable and no env override, hooks resolve to the
     traced path (the zero-mirroring default)."""

@@ -10,10 +10,12 @@ Two differential gates the tentpole owes the rest of the repo:
 2. **Counter vs formula** — :func:`repro.capture.flops.eqn_flops` on each
    captured kernel's traced ``pallas_call`` must reproduce the hooks'
    hand-written FLOP formulas: exactly for STREAM / token-gather /
-   MoE-dispatch / SSM-ema (whose traced paths now pass ``flops=None`` and
-   rely on the counter), and within a small tolerance for
-   flash-attention / paged-KV / SSM-expand, whose formulas round softmax
-   and chunk-mask epilogues to flat per-score constants.
+   MoE-dispatch (whose traced paths now pass ``flops=None`` and rely on
+   the counter), and within a small tolerance for flash-attention /
+   paged-KV / SSM, whose formulas round softmax and chunk-mask epilogues
+   to flat per-score constants.  The SSM kernels also run their prefix
+   sums as lower-triangular MXU matmuls, which the recurrence's formula
+   leaves out; the gate adds them back.
 
 Plus unit coverage of the model walker's region algebra (scan slicing,
 carry ping-pong, transparent aliasing, dense-dot lowering, windowed
@@ -87,9 +89,19 @@ _TOL = {
     "gather": 0.0,
     "moe": 0.0,
     "ssm": 0.01,       # ema exact; expand folds mask ops into 5*C*d
+    # (both after adding the prefix-sum matmuls, _ssm_prefix_flops)
     "flashattn": 0.005,
     "pagedkv": 0.05,
 }
+
+
+def _ssm_prefix_flops(geo: dict) -> float:
+    """Operations of the SSM kernels' ``tril(1) @ v`` prefix sums over one
+    1-core launch: a [C, C] x [C, D] matmul per chunk for each running sum
+    (ema: the decay product and the state sum; expand: the decay
+    product)."""
+    sums = 2 if geo["op"] == "ema" else 1
+    return sums * 2.0 * geo["chunk"] * geo["seq_len"] * geo["d"]
 
 
 @pytest.mark.parametrize(
@@ -112,6 +124,8 @@ def test_counter_matches_hook_formula(spec, monkeypatch):
     finally:
         J.clear_memo()   # drop spy-built captures from the shared memo
     assert counted, f"{spec.name}: traced path never captured an eqn"
+    if spec.kernel == "ssm":
+        formula += _ssm_prefix_flops(dict(spec.geometry))
     tol = _TOL[spec.kernel]
     if tol == 0.0:
         assert counted["flops"] == formula == traced.flops, spec.name

@@ -135,19 +135,33 @@ class TestHloAnalysis:
         # compute-bound: high AI
         rt = hlo_analysis.RooflineTerms(
             name="x", chips=1, hlo_flops=1e15, hlo_bytes=1e9,
-            collective_bytes=0, model_flops=1e15)
+            collective_bytes=0, model_flops=1e15, hw=hw)
         assert rt.bottleneck_class == "compute"
         assert rt.mfu_bound == pytest.approx(1.0)
         # memory-bound
         rt = hlo_analysis.RooflineTerms(
             name="x", chips=1, hlo_flops=1e12, hlo_bytes=1e12,
-            collective_bytes=0)
+            collective_bytes=0, hw=hw)
         assert rt.bottleneck_class == "hbm"
         # latency: sub-100us step
         rt = hlo_analysis.RooflineTerms(
             name="x", chips=256, hlo_flops=1e9, hlo_bytes=1e6,
-            collective_bytes=0)
+            collective_bytes=0, hw=hw)
         assert rt.bottleneck_class == "latency"
+
+    def test_peak_table_is_keyed_by_device_kind(self):
+        assert hlo_analysis.device_spec("TPU v5 lite") is hlo_analysis.TPU_V5E
+
+    def test_unknown_device_kind_is_an_error(self):
+        """No peak is assumed: an unknown kind, and by default the device
+        the tests run on (a CPU), raise rather than read as a v5e."""
+        with pytest.raises(ValueError, match="no peak table entry"):
+            hlo_analysis.device_spec("TPU v9 imaginary")
+        if jax.devices()[0].device_kind not in hlo_analysis.PEAKS:
+            with pytest.raises(ValueError, match="no peak table entry"):
+                hlo_analysis.RooflineTerms(
+                    name="x", chips=1, hlo_flops=1.0, hlo_bytes=1.0,
+                    collective_bytes=0)
 
 
 @pytest.mark.slow

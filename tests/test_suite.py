@@ -365,6 +365,37 @@ class TestProcessFanOut:
         runner = SuiteRunner(reg, cores=CORES, processes=1)
         assert len(runner.roster()) == 2  # no pickle requirement at 1
 
+    @pytest.mark.parametrize("processes", [2, 4])
+    def test_jax_backend_refuses_fan_out(self, processes):
+        """The jax scan runs on the accelerator, which one process holds:
+        any pool is refused before anything is simulated."""
+        runner = SuiteRunner(self._trimmed_registry(), cores=CORES,
+                             backend="jax", processes=processes)
+        with pytest.raises(ValueError, match="--processes 1"):
+            runner.compute_all()
+        assert runner.stats.computed == 0
+
+    def test_pool_workers_are_spawned_on_cpu(self, monkeypatch):
+        """Workers inherit JAX_PLATFORMS=cpu, so none of them can claim the
+        accelerator; the parent's environment is restored afterwards."""
+        import os
+
+        from repro.suite import runner as R
+
+        seen = []
+        real_pool = R.ProcessPoolExecutor
+
+        def spy(*args, **kwargs):
+            seen.append(os.environ.get("JAX_PLATFORMS"))
+            return real_pool(*args, **kwargs)
+
+        monkeypatch.setattr(R, "ProcessPoolExecutor", spy)
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        rows = SuiteRunner(self._trimmed_registry(), cores=CORES,
+                           processes=2).roster()
+        assert len(rows) == 3 and seen == ["cpu"]
+        assert "JAX_PLATFORMS" not in os.environ
+
 
 # --------------------------------------------------------------------------
 # Substrate + CLI
