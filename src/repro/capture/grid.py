@@ -37,6 +37,7 @@ contract ``refs == loads + stores == addresses.size`` on full walks, and a
 
 from __future__ import annotations
 
+import math
 import weakref
 from dataclasses import dataclass
 from typing import Callable
@@ -309,8 +310,7 @@ def _walk(cap: GridCapture, *, count_only: bool,
     else:
         base = {op.name: bases[op.name] for op in cap.operands}
 
-    steps = list(np.ndindex(*cap.grid))
-    n_steps = len(steps)
+    n_steps = math.prod(cap.grid)
     if n_steps == 0:
         footprint = sum({op.name: op.words for op in cap.operands}.values())
         return CaptureResult(
@@ -335,30 +335,32 @@ def _walk(cap: GridCapture, *, count_only: bool,
         # Tiny launches (whole-model traces are thousands of small ops):
         # mask setup costs more than just walking the steps.
         return _walk_loop(cap, count_only=count_only, bases=bases)
-    tables = [_op_table(op, steps) for op in cap.operands]
+    with obs.span("capture.walk.schedule"):
+        steps = list(np.ndindex(*cap.grid))
+        tables = [_op_table(op, steps) for op in cap.operands]
 
-    # Merged change masks per operand name (inputs consult the last index
-    # written by ANY same-named operand, outputs included).
-    by_name: dict[str, list[int]] = {}
-    for oi, op in enumerate(cap.operands):
-        by_name.setdefault(op.name, []).append(oi)
-    emit = np.zeros((len(cap.operands), n_steps), dtype=bool)
-    for name, ois in by_name.items():
-        k = len(ois)
-        merged = np.stack([tables[oi] for oi in ois], axis=1)  # (n, k, r)
-        flat = merged.reshape(n_steps * k, -1)
-        changed = np.empty(n_steps * k, dtype=bool)
-        changed[0] = True
-        np.any(flat[1:] != flat[:-1], axis=1, out=changed[1:])
-        changed = changed.reshape(n_steps, k)
-        for j, oi in enumerate(ois):
-            if cap.operands[oi].role == "in":
-                emit[oi] = changed[:, j]
-    for oi, op in enumerate(cap.operands):
-        if op.role != "in":
-            t = tables[oi]
-            emit[oi, -1] = True
-            np.any(t[1:] != t[:-1], axis=1, out=emit[oi, :-1])
+        # Merged change masks per operand name (inputs consult the last
+        # index written by ANY same-named operand, outputs included).
+        by_name: dict[str, list[int]] = {}
+        for oi, op in enumerate(cap.operands):
+            by_name.setdefault(op.name, []).append(oi)
+        emit = np.zeros((len(cap.operands), n_steps), dtype=bool)
+        for name, ois in by_name.items():
+            k = len(ois)
+            merged = np.stack([tables[oi] for oi in ois], axis=1)  # (n,k,r)
+            flat = merged.reshape(n_steps * k, -1)
+            changed = np.empty(n_steps * k, dtype=bool)
+            changed[0] = True
+            np.any(flat[1:] != flat[:-1], axis=1, out=changed[1:])
+            changed = changed.reshape(n_steps, k)
+            for j, oi in enumerate(ois):
+                if cap.operands[oi].role == "in":
+                    emit[oi] = changed[:, j]
+        for oi, op in enumerate(cap.operands):
+            if op.role != "in":
+                t = tables[oi]
+                emit[oi, -1] = True
+                np.any(t[1:] != t[:-1], axis=1, out=emit[oi, :-1])
 
     loads = stores = 0
     if count_only:
@@ -374,25 +376,27 @@ def _walk(cap: GridCapture, *, count_only: bool,
         # lexicographic order — the scalar walker's emission order.  All
         # of one operand's blocks tile in a single batched call, then land
         # at their events' offsets in the output stream.
-        si_arr, oi_arr = np.nonzero(emit.T)
-        bw = np.array([_block_words(op) for op in cap.operands],
-                      dtype=np.int64)
-        sizes = bw[oi_arr]
-        ends = np.cumsum(sizes)
-        addr = np.empty(int(ends[-1]) if ends.size else 0, dtype=np.int64)
-        for oi, op in enumerate(cap.operands):
-            sel = np.flatnonzero(oi_arr == oi)
-            if not sel.size:
-                continue
-            tiles = _tile_words_batch(op, tables[oi][si_arr[sel]],
-                                      base[op.name])
-            pos = ((ends[sel] - sizes[sel])[:, None]
-                   + np.arange(tiles.shape[1], dtype=np.int64)[None, :])
-            addr[pos] = tiles
-            if op.role == "in":
-                loads += tiles.size
-            else:
-                stores += tiles.size
+        with obs.span("capture.walk.emit"):
+            si_arr, oi_arr = np.nonzero(emit.T)
+            bw = np.array([_block_words(op) for op in cap.operands],
+                          dtype=np.int64)
+            sizes = bw[oi_arr]
+            ends = np.cumsum(sizes)
+            addr = np.empty(int(ends[-1]) if ends.size else 0,
+                            dtype=np.int64)
+            for oi, op in enumerate(cap.operands):
+                sel = np.flatnonzero(oi_arr == oi)
+                if not sel.size:
+                    continue
+                tiles = _tile_words_batch(op, tables[oi][si_arr[sel]],
+                                          base[op.name])
+                pos = ((ends[sel] - sizes[sel])[:, None]
+                       + np.arange(tiles.shape[1], dtype=np.int64)[None, :])
+                addr[pos] = tiles
+                if op.role == "in":
+                    loads += tiles.size
+                else:
+                    stores += tiles.size
 
     footprint = sum({op.name: op.words for op in cap.operands}.values())
     return CaptureResult(

@@ -176,121 +176,125 @@ def _replay_level_chunked(blocks, sets: int, ways: int, *, chunk: int,
     """
     obs.count("stream.level")
     # -- pass 1: collapse + prev per block, persistent line table ---------
-    collapsed = _Blocks(spill, tag=f"lvl{sets}")
-    tbl_lines = np.zeros(0, dtype=np.int64)
-    tbl_gidx = np.zeros(0, dtype=np.int64)
-    set_counts = np.zeros(sets, dtype=np.int64)
-    last_line: int | None = None
-    n = 0
-    m = 0
-    distinct = 0
-    for blk in blocks:
-        b = int(blk.size)
-        n += b
-        if not b:
-            continue
-        keep = np.empty(b, dtype=bool)
-        keep[0] = last_line is None or int(blk[0]) != last_line
-        np.not_equal(blk[1:], blk[:-1], out=keep[1:])
-        last_line = int(blk[-1])
-        cl = blk[keep]
-        k = int(cl.size)
-        if not k:
-            continue
-        prev_in = _block_prev(cl)
-        prev_g = np.where(prev_in >= 0, prev_in + m, -1)
-        bcold = np.flatnonzero(prev_in < 0)
-        if bcold.size:
-            ccl = cl[bcold]
-            pos = np.searchsorted(tbl_lines, ccl)
-            inb = pos < tbl_lines.size
-            match = np.zeros(bcold.size, dtype=bool)
-            match[inb] = tbl_lines[pos[inb]] == ccl[inb]
-            prev_g[bcold[match]] = tbl_gidx[pos[match]]
-        cold = prev_g < 0
-        distinct += int(cold.sum())
-        set_counts += np.bincount(cl % sets, minlength=sets)
-        # newest occurrence per line in this block -> table update
-        order = np.argsort(cl, kind="stable")
-        sorted_cl = cl[order]
-        ends = np.ones(k, dtype=bool)
-        ends[:-1] = sorted_cl[1:] != sorted_cl[:-1]
-        tbl_lines, tbl_gidx = _merge_table(
-            tbl_lines, tbl_gidx, sorted_cl[ends], order[ends] + m)
-        collapsed.append(np.stack([cl, prev_g], axis=1))
-        m += k
-    del tbl_lines, tbl_gidx
+    with obs.span("sim.chunked.collapse"):
+        collapsed = _Blocks(spill, tag=f"lvl{sets}")
+        tbl_lines = np.zeros(0, dtype=np.int64)
+        tbl_gidx = np.zeros(0, dtype=np.int64)
+        set_counts = np.zeros(sets, dtype=np.int64)
+        last_line: int | None = None
+        n = 0
+        m = 0
+        distinct = 0
+        for blk in blocks:
+            b = int(blk.size)
+            n += b
+            if not b:
+                continue
+            keep = np.empty(b, dtype=bool)
+            keep[0] = last_line is None or int(blk[0]) != last_line
+            np.not_equal(blk[1:], blk[:-1], out=keep[1:])
+            last_line = int(blk[-1])
+            cl = blk[keep]
+            k = int(cl.size)
+            if not k:
+                continue
+            prev_in = _block_prev(cl)
+            prev_g = np.where(prev_in >= 0, prev_in + m, -1)
+            bcold = np.flatnonzero(prev_in < 0)
+            if bcold.size:
+                ccl = cl[bcold]
+                pos = np.searchsorted(tbl_lines, ccl)
+                inb = pos < tbl_lines.size
+                match = np.zeros(bcold.size, dtype=bool)
+                match[inb] = tbl_lines[pos[inb]] == ccl[inb]
+                prev_g[bcold[match]] = tbl_gidx[pos[match]]
+            cold = prev_g < 0
+            distinct += int(cold.sum())
+            set_counts += np.bincount(cl % sets, minlength=sets)
+            # newest occurrence per line in this block -> table update
+            order = np.argsort(cl, kind="stable")
+            sorted_cl = cl[order]
+            ends = np.ones(k, dtype=bool)
+            ends[:-1] = sorted_cl[1:] != sorted_cl[:-1]
+            tbl_lines, tbl_gidx = _merge_table(
+                tbl_lines, tbl_gidx, sorted_cl[ends], order[ends] + m)
+            collapsed.append(np.stack([cl, prev_g], axis=1))
+            m += k
+        del tbl_lines, tbl_gidx
 
     # -- pass 2: route collapsed refs to set stripes ----------------------
-    stripe_of_set = _stripes_for(set_counts, chunk)
-    nstripes = int(stripe_of_set[-1]) + 1 if sets else 1
-    stripes = [_Blocks(max(spill // max(nstripes, 1), 1 << 20),
-                       tag=f"stripe{sets}")
-               for _ in range(nstripes)]
-    g = 0
-    for arr in collapsed:
-        cl = arr[:, 0]
-        k = int(cl.size)
-        sid = stripe_of_set[cl % sets]
-        order = np.argsort(sid, kind="stable")
-        counts = np.bincount(sid, minlength=nstripes)
-        gidx = np.arange(g, g + k, dtype=np.int64)[order]
-        cl_o = cl[order]
-        prev_o = arr[:, 1][order]
-        lo = 0
-        for s in range(nstripes):
-            c = int(counts[s])
-            if c:
-                stripes[s].append(np.stack(
-                    [gidx[lo:lo + c], cl_o[lo:lo + c], prev_o[lo:lo + c]],
-                    axis=1))
-            lo += c
-        g += k
+    with obs.span("sim.chunked.route"):
+        stripe_of_set = _stripes_for(set_counts, chunk)
+        nstripes = int(stripe_of_set[-1]) + 1 if sets else 1
+        stripes = [_Blocks(max(spill // max(nstripes, 1), 1 << 20),
+                           tag=f"stripe{sets}")
+                   for _ in range(nstripes)]
+        g = 0
+        for arr in collapsed:
+            cl = arr[:, 0]
+            k = int(cl.size)
+            sid = stripe_of_set[cl % sets]
+            order = np.argsort(sid, kind="stable")
+            counts = np.bincount(sid, minlength=nstripes)
+            gidx = np.arange(g, g + k, dtype=np.int64)[order]
+            cl_o = cl[order]
+            prev_o = arr[:, 1][order]
+            lo = 0
+            for s in range(nstripes):
+                c = int(counts[s])
+                if c:
+                    stripes[s].append(np.stack(
+                        [gidx[lo:lo + c], cl_o[lo:lo + c], prev_o[lo:lo + c]],
+                        axis=1))
+                lo += c
+            g += k
 
     # -- pass 3: per-stripe window scans into one global hit array --------
-    hit = np.zeros(m, dtype=bool)
-    for s in range(nstripes):
-        parts = list(stripes[s])
-        stripes[s].close()
-        if not parts:
-            continue
-        obs.count("stream.stripe")
-        arr = parts[0] if len(parts) == 1 else np.concatenate(parts)
-        del parts
-        gidx = arr[:, 0]
-        cl_s = arr[:, 1]
-        prev_g = arr[:, 2]
-        k = int(cl_s.size)
-        has_prev = prev_g >= 0
-        prev_l = np.full(k, -1, dtype=np.int64)
-        prev_l[has_prev] = np.searchsorted(gidx, prev_g[has_prev])
-        cold = ~has_prev
-        hit_c = np.zeros(k, dtype=bool)
-        revisit = np.flatnonzero(has_prev)
-        if revisit.size:
-            sidx = cl_s % sets
-            per_set_distinct = np.bincount(sidx[cold], minlength=sets)
-            psd_r = per_set_distinct[sidx[revisit]]
-            easy = psd_r <= ways
-            hit_c[revisit[easy]] = True
-            queries = revisit[~easy]
-            if queries.size:
-                sd = _contested_sd(cl_s, sidx, prev_l, queries, sets,
-                                   cap=ways, skip_below=ways, scan=scan)
-                hit_c[queries[sd < ways]] = True
-        hit[gidx] = hit_c
+    with obs.span("sim.chunked.scan"):
+        hit = np.zeros(m, dtype=bool)
+        for s in range(nstripes):
+            parts = list(stripes[s])
+            stripes[s].close()
+            if not parts:
+                continue
+            obs.count("stream.stripe")
+            arr = parts[0] if len(parts) == 1 else np.concatenate(parts)
+            del parts
+            gidx = arr[:, 0]
+            cl_s = arr[:, 1]
+            prev_g = arr[:, 2]
+            k = int(cl_s.size)
+            has_prev = prev_g >= 0
+            prev_l = np.full(k, -1, dtype=np.int64)
+            prev_l[has_prev] = np.searchsorted(gidx, prev_g[has_prev])
+            cold = ~has_prev
+            hit_c = np.zeros(k, dtype=bool)
+            revisit = np.flatnonzero(has_prev)
+            if revisit.size:
+                sidx = cl_s % sets
+                per_set_distinct = np.bincount(sidx[cold], minlength=sets)
+                psd_r = per_set_distinct[sidx[revisit]]
+                easy = psd_r <= ways
+                hit_c[revisit[easy]] = True
+                queries = revisit[~easy]
+                if queries.size:
+                    sd = _contested_sd(cl_s, sidx, prev_l, queries, sets,
+                                       cap=ways, skip_below=ways, scan=scan)
+                    hit_c[queries[sd < ways]] = True
+            hit[gidx] = hit_c
 
     # -- pass 4: emit the ordered miss sub-stream, block by block ---------
-    miss_blocks = _Blocks(spill, tag=f"miss{sets}")
-    g = 0
-    for arr in collapsed:
-        cl = arr[:, 0]
-        k = int(cl.size)
-        sub = hit[g:g + k]
-        if k - int(sub.sum()):
-            miss_blocks.append(cl[~sub])
-        g += k
-    collapsed.close()
+    with obs.span("sim.chunked.emit"):
+        miss_blocks = _Blocks(spill, tag=f"miss{sets}")
+        g = 0
+        for arr in collapsed:
+            cl = arr[:, 0]
+            k = int(cl.size)
+            sub = hit[g:g + k]
+            if k - int(sub.sum()):
+                miss_blocks.append(cl[~sub])
+            g += k
+        collapsed.close()
     hits = (n - m) + int(hit.sum())
     return hits, n - hits, miss_blocks, distinct, n
 

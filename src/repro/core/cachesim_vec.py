@@ -168,67 +168,72 @@ class StreamProfile:
         self.n = n
 
         # -- collapse consecutive duplicates (guaranteed hits) -------------
-        keep = np.empty(n, dtype=bool)
-        keep[0] = True
-        np.not_equal(lines[1:], lines[:-1], out=keep[1:])
-        if seg_offsets is not None:
-            # a segment's first ref is never a repeat of the previous
-            # segment's last line: boundaries reset the collapse
-            keep[seg_offsets[seg_offsets < n]] = True
-        cl = lines[keep]
-        m = int(cl.size)
+        with obs.span("sim.profile.collapse"):
+            keep = np.empty(n, dtype=bool)
+            keep[0] = True
+            np.not_equal(lines[1:], lines[:-1], out=keep[1:])
+            if seg_offsets is not None:
+                # a segment's first ref is never a repeat of the previous
+                # segment's last line: boundaries reset the collapse
+                keep[seg_offsets[seg_offsets < n]] = True
+            cl = lines[keep]
+            m = int(cl.size)
 
-        if seg_offsets is None:
-            seg_c = None
-        else:
-            # collapsed ref -> owning segment (duplicate offsets = empty
-            # segments resolve to the non-empty owner via side="right")
-            seg_c = np.searchsorted(
-                seg_offsets, np.flatnonzero(keep), side="right") - 1
+            if seg_offsets is None:
+                seg_c = None
+            else:
+                # collapsed ref -> owning segment (duplicate offsets =
+                # empty segments resolve to the non-empty owner via
+                # side="right")
+                seg_c = np.searchsorted(
+                    seg_offsets, np.flatnonzero(keep), side="right") - 1
 
         # -- previous occurrence of the same line (collapsed index) --------
         # Stable grouping by (segment, line): pack (group, time) into one
         # int64 key when it fits (one fast introsort); otherwise fall back
         # to lexsort.  prev is segment-local by construction, so the first
         # touch in each segment is cold.
-        shift = max(m - 1, 1).bit_length()
-        cmax = int(cl.max())
-        cmin = int(cl.min())
-        if seg_c is None:
-            gkey = cl
-            packable = cmin >= 0 and cmax < (1 << (62 - shift))
-        else:
-            span = cmax - cmin + 1
-            packable = nseg * span < (1 << (62 - shift))
-            gkey = (seg_c * span + (cl - cmin)) if packable else None
-        if gkey is not None and packable:
-            order = np.argsort((gkey << shift) | np.arange(m, dtype=np.int64))
-            sorted_g = gkey[order]
-        elif seg_c is None:
-            order = np.lexsort((np.arange(m, dtype=np.int64), cl))
-            sorted_g = cl[order]
-        else:
-            order = np.lexsort((np.arange(m, dtype=np.int64), cl, seg_c))
-            sorted_g = None  # compare (seg, line) pairwise below
-        if sorted_g is not None:
-            same = sorted_g[1:] == sorted_g[:-1]
-        else:
-            same = ((cl[order][1:] == cl[order][:-1])
-                    & (seg_c[order][1:] == seg_c[order][:-1]))
-        prev = np.full(m, -1, dtype=np.int64)
-        prev[order[1:][same]] = order[:-1][same]
+        with obs.span("sim.profile.order"):
+            shift = max(m - 1, 1).bit_length()
+            cmax = int(cl.max())
+            cmin = int(cl.min())
+            if seg_c is None:
+                gkey = cl
+                packable = cmin >= 0 and cmax < (1 << (62 - shift))
+            else:
+                span = cmax - cmin + 1
+                packable = nseg * span < (1 << (62 - shift))
+                gkey = (seg_c * span + (cl - cmin)) if packable else None
+            if gkey is not None and packable:
+                order = np.argsort((gkey << shift)
+                                   | np.arange(m, dtype=np.int64))
+                sorted_g = gkey[order]
+            elif seg_c is None:
+                order = np.lexsort((np.arange(m, dtype=np.int64), cl))
+                sorted_g = cl[order]
+            else:
+                order = np.lexsort((np.arange(m, dtype=np.int64), cl, seg_c))
+                sorted_g = None  # compare (seg, line) pairwise below
+        with obs.span("sim.profile.prev"):
+            if sorted_g is not None:
+                same = sorted_g[1:] == sorted_g[:-1]
+            else:
+                same = ((cl[order][1:] == cl[order][:-1])
+                        & (seg_c[order][1:] == seg_c[order][:-1]))
+            prev = np.full(m, -1, dtype=np.int64)
+            prev[order[1:][same]] = order[:-1][same]
 
-        self.keep = keep
-        self.cl = cl
-        self.prev = prev
-        self.cold = prev < 0
-        self.distinct = int(self.cold.sum())
-        self.seg = seg_c
-        if seg_c is None:
-            self.seg_distinct = None
-        else:
-            self.seg_distinct = np.bincount(
-                seg_c[self.cold], minlength=nseg)
+            self.keep = keep
+            self.cl = cl
+            self.prev = prev
+            self.cold = prev < 0
+            self.distinct = int(self.cold.sum())
+            self.seg = seg_c
+            if seg_c is None:
+                self.seg_distinct = None
+            else:
+                self.seg_distinct = np.bincount(
+                    seg_c[self.cold], minlength=nseg)
 
     @property
     def nbytes(self) -> int:
@@ -327,26 +332,30 @@ def _jax_window_kernel():
 
 
 def _jax_window_counts(kern, q_dev, lo, thr, span, chunk) -> np.ndarray:
-    """One chunk of window-first counts on device.
+    """One chunk of window-first counts on device: one launch of the
+    kernel (the ``sim.scan.launch`` span).
 
     Row counts are padded to the next power of two (pad rows: empty
     window, thr below any q value) so recompilation is O(log rows) per
     static ``chunk`` instead of one compile per distinct row count.
     """
-    rows = int(lo.size)
-    padded = 1 << (rows - 1).bit_length() if rows > 1 else 1
-    lo32 = np.zeros(padded, dtype=np.int32)
-    thr32 = np.full(padded, -2, dtype=np.int32)
-    span32 = np.zeros(padded, dtype=np.int32)
-    lo32[:rows] = lo
-    thr32[:rows] = thr
-    span32[:rows] = span
-    shape = (int(q_dev.shape[0]), padded, int(chunk))
-    if shape not in _JAX_SHAPES:
-        _JAX_SHAPES.add(shape)
-        obs.count("scan.jax.programs")
-    out = kern(q_dev, lo32, thr32, span32, int(chunk))
-    return np.asarray(out)[:rows].astype(np.int64)
+    with obs.span("sim.scan.launch"):
+        rows = int(lo.size)
+        padded = 1 << (rows - 1).bit_length() if rows > 1 else 1
+        lo32 = np.zeros(padded, dtype=np.int32)
+        thr32 = np.full(padded, -2, dtype=np.int32)
+        span32 = np.zeros(padded, dtype=np.int32)
+        lo32[:rows] = lo
+        thr32[:rows] = thr
+        span32[:rows] = span
+        shape = (int(q_dev.shape[0]), padded, int(chunk))
+        if shape not in _JAX_SHAPES:
+            _JAX_SHAPES.add(shape)
+            obs.count("scan.jax.programs")
+        out = kern(q_dev, lo32, thr32, span32, int(chunk))
+        with obs.span("sim.scan.wait"):
+            counts = np.asarray(out)
+        return counts[:rows].astype(np.int64)
 
 
 def _contested_sd(cl, sidx, prev, queries, sets, cap, skip_below,
@@ -373,38 +382,41 @@ def _contested_sd(cl, sidx, prev, queries, sets, cap, skip_below,
     falls back to NumPy: a stream past the int32 guard raises.
     """
     m = int(cl.size)
-    if sets <= (1 << 8):
-        sort_key = sidx.astype(np.uint8)      # radix sort
-    elif sets <= (1 << 16):
-        sort_key = sidx.astype(np.uint16)
-    else:
-        sort_key = sidx
-    order = np.argsort(sort_key, kind="stable")
-    pos = np.empty(m, dtype=np.int64)       # global idx -> set-major slot
-    pos[order] = np.arange(m, dtype=np.int64)
-    starts = np.zeros(sets + 1, dtype=np.int64)
-    np.cumsum(np.bincount(sidx, minlength=sets), out=starts[1:])
-    loc = pos - starts[sidx]                # position within own set
-    # q[slot]: set-local index of that access's previous occurrence (-1 if
-    # cold).  Same line -> same set, so prev's local index is comparable.
-    q_global = np.where(prev >= 0, loc[prev], -1)
-    # set-local indices fit int32 far past any roster stream; the narrow
-    # dtype halves the gather-compare traffic of the window scan below
-    qdt = np.int32 if m < (1 << 31) else np.int64
-    q = np.empty(m, dtype=qdt)
-    q[pos] = q_global
+    with obs.span("sim.scan.layout"):
+        if sets <= (1 << 8):
+            sort_key = sidx.astype(np.uint8)      # radix sort
+        elif sets <= (1 << 16):
+            sort_key = sidx.astype(np.uint16)
+        else:
+            sort_key = sidx
+        order = np.argsort(sort_key, kind="stable")
+        pos = np.empty(m, dtype=np.int64)     # global idx -> set-major slot
+        pos[order] = np.arange(m, dtype=np.int64)
+        starts = np.zeros(sets + 1, dtype=np.int64)
+        np.cumsum(np.bincount(sidx, minlength=sets), out=starts[1:])
+        loc = pos - starts[sidx]              # position within own set
+        # q[slot]: set-local index of that access's previous occurrence
+        # (-1 if cold).  Same line -> same set, so prev's local index is
+        # comparable.
+        q_global = np.where(prev >= 0, loc[prev], -1)
+        # set-local indices fit int32 far past any roster stream; the
+        # narrow dtype halves the gather-compare traffic of the window
+        # scan below
+        qdt = np.int32 if m < (1 << 31) else np.int64
+        q = np.empty(m, dtype=qdt)
+        q[pos] = q_global
 
-    # Window of query i: set-local (q_i, loc_i), i.e. set-major slots
-    # [pos[prev[i]]+1, pos[i]).  Window-first accesses j are those with
-    # q[j] <= q_i; their count is the stack distance.
-    threshold = q_global[queries].astype(qdt)
-    win_lo = pos[prev[queries]] + 1
-    win_hi = pos[queries]
+        # Window of query i: set-local (q_i, loc_i), i.e. set-major slots
+        # [pos[prev[i]]+1, pos[i]).  Window-first accesses j are those
+        # with q[j] <= q_i; their count is the stack distance.
+        threshold = q_global[queries].astype(qdt)
+        win_lo = pos[prev[queries]] + 1
+        win_hi = pos[queries]
 
-    sd = np.zeros(queries.size, dtype=np.int64)
-    # stack distance <= window length: windows below the smallest
-    # associativity hit everywhere without scanning
-    live = np.flatnonzero(win_hi - win_lo >= skip_below)
+        sd = np.zeros(queries.size, dtype=np.int64)
+        # stack distance <= window length: windows below the smallest
+        # associativity hit everywhere without scanning
+        live = np.flatnonzero(win_hi - win_lo >= skip_below)
 
     jx = None
     if scan == "jax":

@@ -32,8 +32,15 @@ Every event is one JSON object on its own line, written with a single
 ``write()`` call to a file opened in append mode — concurrent processes
 interleave whole lines, never fragments, so one file collects the merged
 stream.  Span events carry ``pid``/``tid`` tags; ``ts`` is microseconds
-since the epoch (wall clock, comparable across processes) and ``dur`` is
-microseconds measured on ``perf_counter``.
+since the epoch and ``dur`` microseconds, both read from
+``perf_counter_ns`` against one wall origin taken when the process
+imports ``obs`` (comparable across processes, and a nested span always
+ends inside its parent).
+
+Where the process has imported jax, every span is also entered as a
+``jax.profiler.TraceAnnotation`` of the same name, so a JAX profiler
+trace shows the program's spans on its host plane, on the clock of the
+device ops.
 
 Reading a trace
 ---------------
@@ -180,8 +187,23 @@ def _jsonable(v):
     return v if isinstance(v, (str, int, float, bool, type(None))) else str(v)
 
 
+# One wall origin per process: a span's ``ts`` is this origin plus the
+# ``perf_counter_ns`` elapsed since, so ``ts`` and ``dur`` share one clock
+# and a child span never ends past its parent.
+_WALL_MINUS_PERF_NS = time.time_ns() - time.perf_counter_ns()
+
+
+def _annotation(name: str):
+    """The JAX profiler's host annotation for ``name``, or ``None`` when
+    jax has not been imported (``obs`` never imports it)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    return jax.profiler.TraceAnnotation(name)
+
+
 class _Span:
-    __slots__ = ("_sink", "name", "tags", "_ts_us", "_t0")
+    __slots__ = ("_sink", "name", "tags", "_ann", "_t0_ns")
 
     def __init__(self, sink: _Sink, name: str, tags: dict) -> None:
         self._sink = sink
@@ -189,19 +211,27 @@ class _Span:
         self.tags = tags
 
     def __enter__(self):
-        self._ts_us = time.time_ns() // 1000
-        self._t0 = time.perf_counter()
+        self._ann = _annotation(self.name)
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._t0_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        dur_us = (time.perf_counter() - self._t0) * 1e6
+        t1_ns = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        # start in whole us, end in tenths of a us, both floored on the
+        # one clock: containment survives the rounding
+        ts_us = (_WALL_MINUS_PERF_NS + self._t0_ns) // 1000
+        end_tenths = (_WALL_MINUS_PERF_NS + t1_ns) // 100
         event = {
             "ev": "span",
             "name": self.name,
             "pid": os.getpid(),
             "tid": threading.get_native_id(),
-            "ts": self._ts_us,
-            "dur": round(dur_us, 1),
+            "ts": ts_us,
+            "dur": (end_tenths - 10 * ts_us) / 10,
         }
         if self.tags:
             event["tags"] = {k: _jsonable(v) for k, v in self.tags.items()}
@@ -295,7 +325,7 @@ def _flush_locked(sink: _Sink) -> None:
         sink.write({
             "ev": "counters",
             "pid": os.getpid(),
-            "ts": time.time_ns() // 1000,
+            "ts": (_WALL_MINUS_PERF_NS + time.perf_counter_ns()) // 1000,
             "counters": {k: round(v, 6) for k, v in sorted(delta.items())},
         })
 

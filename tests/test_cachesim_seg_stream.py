@@ -398,6 +398,41 @@ class TestWalkStreamDifferential:
         assert peak < 128 * 2**20, f"peak {peak / 2**20:.0f} MiB"
 
 
+class TestChunkedStageSpans:
+    def test_passes_nest_in_sim_chunked_and_change_nothing(self, tmp_path):
+        """One span per pass of each level; the walks that feed the first
+        level run inside its collapse pass, and the scan's stages inside
+        the scan pass.  Counters are the same with tracing on."""
+        pytest.importorskip("jax")
+        from repro.capture.zoo import get_capture
+
+        from _obs_spans import parents_by_name, span_events
+
+        mc = get_capture("qwen2.5-14b", "decode", 1)
+        cfg = cachesim.host_config(4)
+        want = simulate_chunked(mc.walk_stream(_STREAM_TARGET), cfg,
+                                chunk=997, scan="jax")
+        trace = tmp_path / "t.jsonl"
+        obs.enable(trace)
+        try:
+            got = simulate_chunked(mc.walk_stream(_STREAM_TARGET), cfg,
+                                   chunk=997, scan="jax")
+        finally:
+            obs.disable()
+        assert _counters(got) == _counters(want)
+        events = span_events(trace)
+        parents = parents_by_name(events)
+        for stage in ("collapse", "route", "scan", "emit"):
+            assert parents[f"sim.chunked.{stage}"] == {"sim.chunked"}
+            # one span per pass of each of the three levels
+            assert sum(e["name"] == f"sim.chunked.{stage}"
+                       for e in events) == len(cfg.levels)
+        assert parents["capture.walk"] == {"sim.chunked.collapse"}
+        for name in ("sim.scan.layout", "sim.scan.launch"):
+            assert parents[name] == {"sim.chunked.scan"}
+        assert parents["sim.scan.wait"] == {"sim.scan.launch"}
+
+
 # --------------------------------------------------------------------------
 # Engine contract: simulate_cells, trace sharing, profile store
 # --------------------------------------------------------------------------

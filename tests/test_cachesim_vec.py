@@ -18,6 +18,7 @@ import time
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core import cachesim, cachesim_vec, tracegen
 
 REFS = 4_000  # short traces: the matrix is 84 cells x 2 backends
@@ -382,6 +383,46 @@ class TestTraceMemo:
         resident = sum(m.nbytes() for m in cachesim_vec._MEMOS)
         assert (resident <= cachesim_vec._MEMO_MAX_BYTES
                 or len(cachesim_vec._MEMOS) == 1)
+
+
+# --------------------------------------------------------------------------
+# Stage spans: where the profile build and the scan spend their time
+# --------------------------------------------------------------------------
+class TestStageSpans:
+    def _run(self, traced):
+        """One segmented ``simulate_many`` on the jax scan, over fresh
+        copies of three families' traces (every profile is built)."""
+        reqs = [(_FAMILY_WORKLOADS[f].trace(4).addresses.copy(),
+                 [cachesim.host_config(4), cachesim.ndp_config(4)], {})
+                for f in ("contended", "irregular", "stream")]
+        if traced is None:
+            return cachesim_vec.simulate_many(reqs, scan="jax")
+        obs.enable(traced)
+        try:
+            return cachesim_vec.simulate_many(reqs, scan="jax")
+        finally:
+            obs.disable()
+
+    def test_stages_nest_in_their_layer_and_change_nothing(self, tmp_path):
+        pytest.importorskip("jax")
+        from _obs_spans import parents_by_name, span_events
+
+        off = self._run(None)
+        trace = tmp_path / "t.jsonl"
+        on = self._run(trace)
+        assert [[(s.level_hits, s.level_misses, s.lines_touched)
+                 for s in sims] for sims in on] == \
+            [[(s.level_hits, s.level_misses, s.lines_touched)
+              for s in sims] for sims in off]
+        parents = parents_by_name(span_events(trace))
+        want = {"sim.profile.collapse": {"sim.profile"},
+                "sim.profile.order": {"sim.profile"},
+                "sim.profile.prev": {"sim.profile"},
+                "sim.scan.layout": {"sim.scan"},
+                "sim.scan.launch": {"sim.scan"},
+                "sim.scan.wait": {"sim.scan.launch"}}
+        for name, allowed in want.items():
+            assert parents.get(name) == allowed, name
 
 
 @pytest.mark.slow

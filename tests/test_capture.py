@@ -106,6 +106,37 @@ class TestWalker:
 # --------------------------------------------------------------------------
 # Vectorized walker vs scalar reference (the gate promised in grid.py)
 # --------------------------------------------------------------------------
+class TestWalkStageSpans:
+    def test_stages_nest_in_capture_walk_and_change_nothing(self,
+                                                            tmp_path):
+        """A gridded walk opens its schedule and emit stages inside
+        ``capture.walk``; a count-only one emits nothing; addresses are
+        byte-identical with tracing on and off."""
+        from _obs_spans import parents_by_name, span_events
+
+        from repro import obs
+
+        cap = flash_capture.capture(sq=1024, sk=1024, d=64)
+        off = walk(cap)
+        trace = tmp_path / "t.jsonl"
+        obs.enable(trace)
+        try:
+            on = walk(cap)
+            counted = walk(cap, count_only=True)
+        finally:
+            obs.disable()
+        assert on.addresses.tobytes() == off.addresses.tobytes()
+        assert (on.loads, on.stores) == (off.loads, off.stores)
+        assert counted.refs == off.refs
+        events = span_events(trace)
+        assert [e["name"] for e in events] == [
+            "capture.walk.schedule", "capture.walk.emit", "capture.walk",
+            "capture.walk.schedule", "capture.walk"]
+        parents = parents_by_name(events)
+        assert parents["capture.walk.schedule"] == {"capture.walk"}
+        assert parents["capture.walk.emit"] == {"capture.walk"}
+
+
 class TestWalkDifferential:
     """``_walk`` (vectorized) must be byte-identical to ``_walk_loop``
     (the scalar reference) over the captured-kernel roster — addresses,

@@ -2,7 +2,8 @@
 
 Covers the tentpole guarantees one by one: the disabled span path is a
 shared no-op singleton that allocates nothing that survives the
-statement; span events carry pid/tid/ts/dur and nest correctly; counter
+statement; span events carry pid/tid/ts/dur and nest correctly, on one
+clock, mirrored onto the JAX profiler's host plane; counter
 flushes are *deltas* so multi-process streams sum; child processes
 inherit the sink through ``REPRO_TRACE`` and merge into the same file;
 the counters emitted by the simulator hot paths match hand counts on a
@@ -243,6 +244,125 @@ class TestDisabledPathCost:
             site()
         after = sys.getallocatedblocks()
         assert after - before <= 16  # interpreter noise only
+
+
+# --------------------------------------------------------------------------
+# One clock, mirrored onto the JAX profiler
+# --------------------------------------------------------------------------
+class _CountingAnnotation:
+    """Stand-in for ``jax.profiler.TraceAnnotation`` that counts."""
+
+    made = 0
+
+    def __init__(self, name, **kwargs):
+        type(self).made += 1
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+def _stage_work():
+    """One contested-distance scan on the jax backend and one vectorized
+    grid walk that emits addresses: both run stage-span sites."""
+    import numpy as np
+
+    from repro.capture.grid import _walk
+    from repro.core import cachesim_vec
+    from repro.kernels.flash_attention import capture as flash_capture
+
+    # 64 lines over 4 sets of 2 ways: nearly every revisit is contested
+    lines = np.random.default_rng(0).integers(0, 64, 5_000)
+    sets = 4
+    prof = cachesim_vec.StreamProfile(lines)
+    revisit = np.flatnonzero(~prof.cold)
+    sd = cachesim_vec._contested_sd(prof.cl, prof.cl % sets, prof.prev,
+                                    revisit, sets, cap=2, skip_below=1,
+                                    scan="jax")
+    res = _walk(flash_capture.capture(sq=1024, sk=1024, d=64),
+                count_only=False, bases=None)
+    return sd, res.addresses
+
+
+class TestProfilerClock:
+    def test_spans_mirrored_onto_the_profiler_host_plane(self, tmp_path):
+        import time
+
+        jax = pytest.importorskip("jax")
+        trace = tmp_path / "t.jsonl"
+        obs.enable(trace)
+        jax.profiler.start_trace(str(tmp_path / "xplane"))
+        try:
+            with obs.span("mirror.outer"):
+                time.sleep(0.002)
+                with obs.span("mirror.inner"):
+                    time.sleep(0.003)
+                time.sleep(0.002)
+        finally:
+            jax.profiler.stop_trace()
+            obs.disable()
+        got = {e["name"]: e for e in _events(trace)}
+        data = jax.profiler.ProfileData.from_file(
+            str(next((tmp_path / "xplane").rglob("*.xplane.pb"))))
+        seen = {}
+        for plane in data.planes:
+            if not plane.name.startswith("/host:"):
+                continue
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name in got:
+                        seen[ev.name] = (ev.start_ns, ev.duration_ns)
+        assert set(seen) == {"mirror.outer", "mirror.inner"}
+        for name, (_, dur_ns) in seen.items():
+            dur_ns_obs = got[name]["dur"] * 1e3
+            assert abs(dur_ns - dur_ns_obs) <= max(0.05 * dur_ns_obs, 50e3)
+        (o0, od), (i0, idur) = seen["mirror.outer"], seen["mirror.inner"]
+        assert o0 <= i0 and i0 + idur <= o0 + od
+
+    def test_child_never_ends_past_its_parent(self, tmp_path):
+        from _obs_spans import bounds
+
+        trace = tmp_path / "t.jsonl"
+        obs.enable(trace)
+        for _ in range(200):
+            with obs.span("clock.parent"):
+                with obs.span("clock.child"):
+                    pass
+        obs.disable()
+        evs = _events(trace)
+        for child, par in zip(evs[::2], evs[1::2]):
+            assert (child["name"], par["name"]) == ("clock.child",
+                                                    "clock.parent")
+            (c0, c1), (p0, p1) = bounds(child), bounds(par)
+            assert p0 <= c0 <= c1 <= p1
+
+    def test_off_path_makes_no_annotation_and_no_event(self, tmp_path,
+                                                      monkeypatch):
+        jax = pytest.importorskip("jax")
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation",
+                            _CountingAnnotation)
+        _CountingAnnotation.made = 0
+        assert not obs.enabled()
+        assert obs.span("sim.scan.layout") is obs._NULL_SPAN
+        sd_off, addr_off = _stage_work()
+        assert _CountingAnnotation.made == 0
+        assert list(tmp_path.iterdir()) == []
+
+        # the same work traced: the stub is what the mirror enters, and
+        # the answers do not change
+        trace = tmp_path / "t.jsonl"
+        obs.enable(trace)
+        sd_on, addr_on = _stage_work()
+        obs.disable()
+        names = {e["name"] for e in _events(trace) if e["ev"] == "span"}
+        assert {"sim.scan.layout", "sim.scan.launch", "sim.scan.wait",
+                "capture.walk.schedule", "capture.walk.emit"} <= names
+        assert _CountingAnnotation.made == len(
+            [e for e in _events(trace) if e["ev"] == "span"])
+        assert sd_on.tobytes() == sd_off.tobytes()
+        assert addr_on.tobytes() == addr_off.tobytes()
 
 
 # --------------------------------------------------------------------------
