@@ -31,8 +31,9 @@ Counter-identity invariant: for every captured entry, the two paths emit
 (``tests/test_capture_jaxpr.py`` diffs them over the whole legacy roster),
 so suite-store fingerprints, AI columns and class verdicts never depend on
 which path produced a trace.  The walker itself upholds the counter
-contract ``refs == loads + stores == addresses.size`` on full walks, and a
-``count_only`` walk returns the same counters with an empty address array.
+contract ``refs == loads + stores == addresses.size`` on full and windowed
+walks, and a ``count_only`` walk returns the same counters with an empty
+address array.
 """
 
 from __future__ import annotations
@@ -127,8 +128,8 @@ class CaptureResult:
 
     @property
     def refs(self) -> int:
-        # == addresses.size for a full walk; also correct for a
-        # count-only walk, whose address array is empty.
+        # == addresses.size for a full or windowed walk; also correct for
+        # a count-only walk, whose address array is empty.
         return self.loads + self.stores
 
     @property
@@ -219,7 +220,8 @@ def from_jaxpr(fn, args, *, scalar_values=(), flops: float = 0.0,
 
 
 def walk(cap: GridCapture, *, count_only: bool = False,
-         bases: dict[str, int] | None = None) -> CaptureResult:
+         bases: dict[str, int] | None = None,
+         span: tuple[int, int] | None = None) -> CaptureResult:
     """Replay the pipeline schedule and emit the word-address stream.
 
     Arrays are laid out back-to-back in HBM, line-aligned, in operand
@@ -239,12 +241,35 @@ def walk(cap: GridCapture, *, count_only: bool = False,
     layout here, so a single-op model capture is byte-identical to the
     standalone walk (the differential gate in
     ``tests/test_capture_model.py``).
+
+    ``span=(lo, hi)`` emits only references ``[lo, hi)`` of the stream
+    (clipped to its length): ``addresses`` equals ``walk(cap).addresses
+    [lo:hi]`` and ``loads``/``stores`` count that slice by role.  A
+    vectorized walk tiles only the blocks that overlap the span, so a
+    window's slice of a large op costs the slice, not the op.
     """
+    if span is not None:
+        if count_only:
+            raise ValueError("span needs addresses; count_only emits none")
+        if not 0 <= span[0] <= span[1]:
+            raise ValueError(f"span {span}: need 0 <= lo <= hi")
     with obs.span("capture.walk", kernel=cap.name, count_only=count_only):
-        res = _walk(cap, count_only=count_only, bases=bases)
+        res = _walk(cap, count_only=count_only, bases=bases, span=span)
     obs.count("capture.walk.calls")
     obs.count("capture.walk.refs", res.refs)
     return res
+
+
+def _clip_span(span: tuple[int, int] | None, total: int) -> tuple[int, int]:
+    """``span`` clipped to a stream of ``total`` refs (all of it when
+    ``span`` is None); counts a span short of the whole stream."""
+    if span is None:
+        return 0, total
+    lo, hi = min(span[0], total), min(span[1], total)
+    if hi - lo < total:
+        obs.count("capture.walk.window_calls")
+        obs.count("capture.walk.skipped_refs", total - (hi - lo))
+    return lo, hi
 
 
 def _block_words(op: OperandSpec) -> int:
@@ -283,7 +308,8 @@ def _op_table(op: OperandSpec, steps: list[tuple[int, ...]]) -> np.ndarray:
 
 
 def _walk(cap: GridCapture, *, count_only: bool,
-          bases: dict[str, int] | None) -> CaptureResult:
+          bases: dict[str, int] | None,
+          span: tuple[int, int] | None = None) -> CaptureResult:
     """Vectorized pipeline replay.
 
     Emission decisions are mask arithmetic over per-operand index tables;
@@ -298,6 +324,8 @@ def _walk(cap: GridCapture, *, count_only: bool,
       sequence of every same-named operand;
     - an *output* writes back when its own next-step index differs (or at
       the final step).
+
+    With a ``span``, only the events whose blocks overlap it are tiled.
     """
     if bases is None:
         base: dict[str, int] = {}
@@ -334,7 +362,8 @@ def _walk(cap: GridCapture, *, count_only: bool,
     if n_steps * len(cap.operands) <= 64:
         # Tiny launches (whole-model traces are thousands of small ops):
         # mask setup costs more than just walking the steps.
-        return _walk_loop(cap, count_only=count_only, bases=bases)
+        return _walk_loop(cap, count_only=count_only, bases=bases,
+                          span=span)
     with obs.span("capture.walk.schedule"):
         steps = list(np.ndindex(*cap.grid))
         tables = [_op_table(op, steps) for op in cap.operands]
@@ -382,21 +411,37 @@ def _walk(cap: GridCapture, *, count_only: bool,
                           dtype=np.int64)
             sizes = bw[oi_arr]
             ends = np.cumsum(sizes)
-            addr = np.empty(int(ends[-1]) if ends.size else 0,
-                            dtype=np.int64)
+            lo, hi = _clip_span(span, int(ends[-1]) if ends.size else 0)
+            # The stream is the events' blocks back to back, so the events
+            # overlapping [lo, hi) are one run [e0, e1): tile only those.
+            e0 = int(np.searchsorted(ends, lo, side="right"))
+            e1 = int(np.searchsorted(ends - sizes, hi, side="left"))
+            si_arr, oi_arr = si_arr[e0:e1], oi_arr[e0:e1]
+            sizes, ends = sizes[e0:e1], ends[e0:e1]
+            first = int(ends[0] - sizes[0]) if ends.size else lo
+            last = int(ends[-1]) if ends.size else lo
+            addr = np.empty(last - first, dtype=np.int64)
             for oi, op in enumerate(cap.operands):
                 sel = np.flatnonzero(oi_arr == oi)
                 if not sel.size:
                     continue
                 tiles = _tile_words_batch(op, tables[oi][si_arr[sel]],
                                           base[op.name])
-                pos = ((ends[sel] - sizes[sel])[:, None]
+                pos = ((ends[sel] - sizes[sel] - first)[:, None]
                        + np.arange(tiles.shape[1], dtype=np.int64)[None, :])
                 addr[pos] = tiles
                 if op.role == "in":
                     loads += tiles.size
                 else:
                     stores += tiles.size
+            if (first, last) != (lo, hi):
+                # the run's first and last blocks may stick out of the span
+                for e, out in ((0, lo - first), (-1, last - hi)):
+                    if cap.operands[oi_arr[e]].role == "in":
+                        loads -= out
+                    else:
+                        stores -= out
+                addr = addr[lo - first:hi - first]
 
     footprint = sum({op.name: op.words for op in cap.operands}.values())
     return CaptureResult(
@@ -411,11 +456,12 @@ def _walk(cap: GridCapture, *, count_only: bool,
 
 
 def _walk_loop(cap: GridCapture, *, count_only: bool,
-               bases: dict[str, int] | None) -> CaptureResult:
+               bases: dict[str, int] | None,
+               span: tuple[int, int] | None = None) -> CaptureResult:
     """Scalar reference walker — the schedule spelled out one step at a
     time.  Serves tiny launches (where mask setup would dominate) and the
     differential gate that diffs it against the vectorized :func:`_walk`
-    over the captured-kernel roster.
+    over the captured-kernel roster.  A ``span`` slices the whole walk.
     """
     if bases is None:
         base: dict[str, int] = {}
@@ -430,6 +476,7 @@ def _walk_loop(cap: GridCapture, *, count_only: bool,
 
     steps = list(np.ndindex(*cap.grid))
     chunks: list[np.ndarray] = []
+    roles: list[str] = []
     loads = stores = 0
     prev_idx: dict[str, tuple[int, ...] | None] = {
         op.name: None for op in cap.operands
@@ -445,6 +492,7 @@ def _walk_loop(cap: GridCapture, *, count_only: bool,
                     else:
                         w = _tile_words(op, bidx, base[op.name])
                         chunks.append(w)
+                        roles.append(op.role)
                         loads += w.size
             else:
                 nidx = (
@@ -457,6 +505,7 @@ def _walk_loop(cap: GridCapture, *, count_only: bool,
                     else:
                         w = _tile_words(op, bidx, base[op.name])
                         chunks.append(w)
+                        roles.append(op.role)
                         stores += w.size
             prev_idx[op.name] = bidx
 
@@ -464,6 +513,17 @@ def _walk_loop(cap: GridCapture, *, count_only: bool,
         np.concatenate(chunks)
         if chunks else np.empty(0, dtype=np.int64)
     )
+    if span is not None:
+        lo, hi = _clip_span(span, addr.size)
+        addr = addr[lo:hi]
+        loads = stores = pos = 0
+        for w, role in zip(chunks, roles):
+            n = max(0, min(pos + w.size, hi) - max(pos, lo))
+            pos += w.size
+            if role == "in":
+                loads += n
+            else:
+                stores += n
     footprint = sum({op.name: op.words for op in cap.operands}.values())
     return CaptureResult(
         name=cap.name,

@@ -103,8 +103,11 @@ class ModelOp:
     capture: GridCapture
     bases: dict[str, int]
 
-    def walk(self, *, count_only: bool = False) -> CaptureResult:
-        if count_only:
+    def walk(self, *, count_only: bool = False,
+             span: tuple[int, int] | None = None) -> CaptureResult:
+        """The op's walk; ``span`` emits only its refs ``[lo, hi)``
+        (:func:`~repro.capture.grid.walk`)."""
+        if count_only and span is None:
             # Count-only walks are pure and repeated (walk_window sizes
             # every op, then whole-step accounting counts them again), so
             # cache on the instance (frozen dataclass → object.__setattr__).
@@ -113,7 +116,8 @@ class ModelOp:
                 got = walk(self.capture, count_only=True, bases=self.bases)
                 object.__setattr__(self, "_counts", got)
             return got
-        return walk(self.capture, count_only=count_only, bases=self.bases)
+        return walk(self.capture, count_only=count_only, bases=self.bases,
+                    span=span)
 
 
 @dataclass
@@ -166,7 +170,8 @@ class ModelCapture:
         it to :func:`repro.core.cachesim_stream.simulate_chunked` (which
         accepts any iterable of address blocks) simulates the whole step
         under a fixed memory ceiling — peak trace memory is the largest
-        single op's walk, regardless of how many megarefs the step emits.
+        single op's walk (with a target, the largest slice of an op in the
+        window), regardless of how many megarefs the step emits.
         Counter identity is structural: with ``target_refs=None`` the
         yielded blocks concatenate to exactly ``walk().addresses``; with a
         target they concatenate to ``walk_window(target_refs, center=
@@ -183,22 +188,37 @@ class ModelCapture:
                     obs.count("capture.model.stream_blocks")
                     yield addr
             return
+        counts, window = self._window(target_refs, center)
+        if window is None:
+            yield from self.walk_stream()
+            return
+        for blk in self._window_blocks(counts, *window):
+            obs.count("capture.model.stream_blocks")
+            yield blk
+
+    def _window(self, target_refs: int, center: float):
+        """Every op's count-only walk and the centred window ``(start,
+        end)`` of ``target_refs`` refs, or None where the step is no
+        longer than the target."""
         if target_refs <= 0:
             raise ValueError("target_refs must be positive")
         counts = [op.walk(count_only=True) for op in self.ops]
         total = sum(r.refs for r in counts)
         if total <= target_refs:
-            yield from self.walk_stream()
-            return
+            return counts, None
         start = int((total - target_refs) * min(max(center, 0.0), 1.0))
-        end = start + target_refs
+        return counts, (start, start + target_refs)
+
+    def _window_blocks(self, counts, start: int, end: int):
+        """Each op's slice of refs ``[start, end)`` of the step, in program
+        order; an op the window cuts emits only its slice."""
         pos = 0
         for op, r in zip(self.ops, counts):
             nxt = pos + r.refs
             if nxt > start and pos < end:
-                blk = op.walk().addresses[max(0, start - pos):end - pos]
+                blk = op.walk(span=(max(0, start - pos),
+                                    min(r.refs, end - pos))).addresses
                 if blk.size:
-                    obs.count("capture.model.stream_blocks")
                     yield blk
             pos = nxt
             if pos >= end:
@@ -211,36 +231,22 @@ class ModelCapture:
         Train steps emit tens of megarefs; simulating all of them buys
         nothing over a steady-state slice (the weight streams repeat layer
         after layer), so the zoo samples one contiguous ``target_refs``
-        window (SimPoint-style, ``center`` picks where).  Per-op lazy
-        walking keeps peak memory at the largest single op — the full
-        multi-hundred-MB trace is never materialized.  Shorter-than-target
-        traces come back whole (callers cycle them, the ``np.resize``
-        convention).  Load/store counters are scaled pro rata; ``flops``
-        stays the whole-step count so AI must be taken against the
-        whole-step ``refs``, not the window length.
+        window (SimPoint-style, ``center`` picks where).  Each op emits
+        only its slice of the window, so peak memory is the window — the
+        full multi-hundred-MB trace is never materialized.
+        Shorter-than-target traces come back whole (callers cycle them,
+        the ``np.resize`` convention).  Load/store counters are scaled pro
+        rata; ``flops`` stays the whole-step count so AI must be taken
+        against the whole-step ``refs``, not the window length.
         """
-        if target_refs <= 0:
-            raise ValueError("target_refs must be positive")
-        counts = [op.walk(count_only=True) for op in self.ops]
-        total = sum(r.refs for r in counts)
-        if total <= target_refs:
+        counts, window = self._window(target_refs, center)
+        if window is None:
             return self.walk()
-        start = int((total - target_refs) * min(max(center, 0.0), 1.0))
-        end = start + target_refs
-        chunks: list[np.ndarray] = []
-        pos = 0
-        for op, r in zip(self.ops, counts):
-            nxt = pos + r.refs
-            if nxt > start and pos < end:
-                addr = op.walk().addresses
-                chunks.append(addr[max(0, start - pos):end - pos])
-            pos = nxt
-            if pos >= end:
-                break
         from repro import obs
 
         obs.count("capture.model.concat")  # windowed traces materialize too
-        addr = np.concatenate(chunks)
+        addr = np.concatenate(list(self._window_blocks(counts, *window)))
+        total = sum(r.refs for r in counts)
         loads = sum(r.loads for r in counts)
         w_loads = int(round(loads * target_refs / total))
         return CaptureResult(

@@ -137,19 +137,24 @@ class TestWalkStageSpans:
         assert parents["capture.walk.emit"] == {"capture.walk"}
 
 
+# The captured-kernel geometries the vectorized-vs-loop gate walks.
+_DIFF_GEOMETRIES = {
+    **{f"stream.{v}": (lambda v=v: stream_capture.capture(v, 2**17))
+       for v in ("copy", "scale", "add", "triad")},
+    "flash.256x512": lambda: flash_capture.capture(sq=256, sk=512, d=64),
+    "flash.512x1024": lambda: flash_capture.capture(sq=512, sk=1024, d=64),
+    "gather": lambda: gather_capture.capture(
+        1024, 128, 64, rng=np.random.default_rng(11)),
+}
+
+
 class TestWalkDifferential:
     """``_walk`` (vectorized) must be byte-identical to ``_walk_loop``
     (the scalar reference) over the captured-kernel roster — addresses,
     counters and footprints, in both full and count-only modes."""
 
     def _captures(self):
-        rng = np.random.default_rng(11)
-        caps = [stream_capture.capture(v, 2**17)
-                for v in ("copy", "scale", "add", "triad")]
-        caps.append(flash_capture.capture(sq=256, sk=512, d=64))
-        caps.append(flash_capture.capture(sq=512, sk=1024, d=64))
-        caps.append(gather_capture.capture(1024, 128, 64, rng=rng))
-        return caps
+        return [build() for build in _DIFF_GEOMETRIES.values()]
 
     def test_full_walk_byte_identical(self):
         from repro.capture.grid import _walk, _walk_loop
@@ -188,6 +193,125 @@ class TestWalkDifferential:
         ref = _walk_loop(cap, count_only=False, bases=None)
         assert np.array_equal(vec.addresses, ref.addresses)
         assert (vec.loads, vec.stores) == (ref.loads, ref.stores)
+
+
+def _qwen_ffn_dot() -> GridCapture:
+    """A Qwen2.5-14B decode FFN projection as the whole-model walker
+    lowers it (bf16, batch 64, 128-wide tiles, k innermost), at a reduced
+    grid (1, 1, 12, 8) in place of (1, 1, 108, 40)."""
+    g, m, k, n = 1, 64, 1024, 1536
+    return GridCapture("dot_general", (g, 1, n // 128, k // 128), operands=(
+        OperandSpec("lhs", "in", (g, m, k), (1, m, 128),
+                    lambda gg, i, j, kk: (gg, i, kk), elems_per_word=4),
+        OperandSpec("rhs", "in", (g, k, n), (1, 128, 128),
+                    lambda gg, i, j, kk: (gg, kk, j), elems_per_word=4),
+        OperandSpec("out", "out", (g, m, n), (1, m, 128),
+                    lambda gg, i, j, kk: (gg, i, j), elems_per_word=4),
+    ))
+
+
+def _tiny_launch() -> GridCapture:
+    """12 step-operand pairs: walked by ``_walk_loop``."""
+    return GridCapture("tiny", (2, 2), operands=(
+        OperandSpec("x", "in", (16, 128), (8, 128), lambda i, j: (i, 0)),
+        OperandSpec("y", "in", (16, 128), (8, 128), lambda i, j: (j, 0)),
+        OperandSpec("o", "out", (16, 128), (8, 128), lambda i, j: (i, 0)),
+    ))
+
+
+_SPAN_GEOMETRIES = {**_DIFF_GEOMETRIES, "qwen.ffn.dot": _qwen_ffn_dot,
+                    "tiny.loop": _tiny_launch}
+_SPANS = ("whole", "prefix", "suffix", "inside_block", "block_boundary",
+          "write_back", "past_end", "empty")
+
+
+def _span(kind: str, cap: GridCapture, full) -> tuple[int, int]:
+    """A span of ``kind`` in ``full``, the walk of ``cap`` with each name
+    based at its own multiple of 2**32."""
+    n = full.refs
+    first = _block_words(cap.operands[0])          # the first event's block
+    out = next(op for op in cap.operands if op.role == "out")
+    at = int(np.flatnonzero(full.addresses >> 32 == _names(cap).index(
+        out.name))[0])                              # first write-back
+    return {
+        "whole": (0, n),
+        "prefix": (0, n // 3),
+        "suffix": (n - n // 3, n),
+        "inside_block": (at + 1, at + _block_words(out) - 1),
+        "block_boundary": (first - 1, first + 1),
+        "write_back": (at - 1, at + _block_words(out) + 1),
+        "past_end": (n // 2, n + 1000),
+        "empty": (n // 2, n // 2),
+    }[kind]
+
+
+def _names(cap: GridCapture) -> list[str]:
+    return list(dict.fromkeys(op.name for op in cap.operands))
+
+
+def _block_words(op: OperandSpec) -> int:
+    return int(np.prod(op.block_shape)) // op.elems_per_word
+
+
+class TestWindowedEmission:
+    """``walk(cap, span=(lo, hi))`` is the full walk's ``[lo:hi]`` slice,
+    byte for byte, with loads and stores counted over the slice by role,
+    on the vectorized path and on the tiny-launch loop path."""
+
+    @pytest.mark.parametrize("kind", _SPANS)
+    @pytest.mark.parametrize("geometry", list(_SPAN_GEOMETRIES))
+    def test_span_is_slice_of_full_walk(self, geometry, kind):
+        from repro import obs
+
+        cap = _SPAN_GEOMETRIES[geometry]()
+        names = _names(cap)
+        bases = {name: i << 32 for i, name in enumerate(names)}
+        full = walk(cap, bases=bases)
+        lo, hi = _span(kind, cap, full)
+        assert 0 <= lo <= hi
+        obs.reset_counters()
+        got = walk(cap, bases=bases, span=(lo, hi))
+        c = obs.counters()
+        want = full.addresses[lo:hi]
+        assert got.addresses.tobytes() == want.tobytes()
+        role = {op.name: op.role for op in cap.operands}
+        loads = sum(int(np.count_nonzero(want >> 32 == i))
+                    for i, name in enumerate(names) if role[name] == "in")
+        assert (got.loads, got.stores) == (loads, want.size - loads)
+        assert got.refs == got.loads + got.stores == got.addresses.size
+        assert c["capture.walk.refs"] == want.size
+        if want.size < full.refs:
+            assert c["capture.walk.window_calls"] == 1
+            assert c["capture.walk.skipped_refs"] == full.refs - want.size
+        else:
+            assert "capture.walk.window_calls" not in c
+            assert "capture.walk.skipped_refs" not in c
+
+    def test_qwen_dot_is_vectorized_and_tiny_launch_is_not(self):
+        # the two added geometries take the two paths
+        for build, steps in ((_qwen_ffn_dot, 96), (_tiny_launch, 4)):
+            cap = build()
+            assert walk(cap).grid_steps == steps
+            assert (steps * len(cap.operands) > 64) == (build is _qwen_ffn_dot)
+
+    def test_bad_spans_rejected(self):
+        cap = _qwen_ffn_dot()
+        with pytest.raises(ValueError):
+            walk(cap, count_only=True, span=(0, 8))
+        for span in ((-1, 8), (9, 8)):
+            with pytest.raises(ValueError):
+                walk(cap, span=span)
+
+    def test_full_walk_counts_no_window(self):
+        from repro import obs
+
+        cap = _qwen_ffn_dot()
+        obs.reset_counters()
+        full = walk(cap)
+        walk(cap, span=(0, full.refs))
+        c = obs.counters()
+        assert c["capture.walk.calls"] == 2
+        assert "capture.walk.window_calls" not in c
 
 
 # --------------------------------------------------------------------------

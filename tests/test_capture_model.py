@@ -306,6 +306,60 @@ def test_walk_stream_counters_vs_concat_counters():
     assert obs.counters()["capture.model.concat"] == 2
 
 
+def _two_dots():
+    """A step of three ops: a vectorized dot (grid (1, 3, 3, 3)), a tanh
+    stream op and a second dot."""
+    big = jax.ShapeDtypeStruct((384, 384), jnp.float32)
+    return capture_model(lambda x, y: jnp.tanh(x @ y) @ y, (big, big),
+                         name="two-dots")
+
+
+@pytest.mark.parametrize("center", [0.0, 0.2, 0.5, 0.8, 1.0])
+def test_windowed_stream_is_slice_of_whole_walk(center):
+    """The streamed window and ``walk_window`` are the whole walk's
+    centred slice, for windows that start and end inside ops; the ops
+    the window cuts emit only their slice."""
+    from repro import obs
+
+    mc = _two_dots()
+    full = mc.walk()
+    sizes = np.array([op.walk(count_only=True).refs for op in mc.ops])
+    assert len(sizes) == 3 and min(sizes) > 0
+    target = sizes[1] + sizes[0] // 2          # cuts at least one op
+    start = int((full.refs - target) * center)
+    want = full.addresses[start:start + target]
+
+    obs.reset_counters()
+    blocks = list(mc.walk_stream(target, center=center))
+    c = obs.counters()
+    assert np.concatenate(blocks).tobytes() == want.tobytes()
+    assert c["capture.model.stream_blocks"] == len(blocks)
+    assert "capture.model.concat" not in c
+    ends = np.cumsum(sizes)
+    kept = np.clip(np.minimum(ends, start + target)
+                   - np.maximum(ends - sizes, start), 0, None)
+    cut = (kept > 0) & (kept < sizes)
+    assert c["capture.walk.window_calls"] == np.count_nonzero(cut) >= 1
+    assert c["capture.walk.skipped_refs"] == int((sizes - kept)[cut].sum())
+    assert c["capture.walk.refs"] == target
+    assert mc.walk_window(target, center=center).addresses.tobytes() \
+        == want.tobytes()
+
+
+def test_whole_walks_count_no_window():
+    from repro import obs
+
+    mc = _two_dots()
+    full = mc.walk(count_only=True)            # sizes each op once
+    obs.reset_counters()
+    mc.walk()
+    list(mc.walk_stream())
+    list(mc.walk_stream(full.refs))            # the window is the step
+    c = obs.counters()
+    assert "capture.walk.window_calls" not in c
+    assert c["capture.walk.refs"] == 3 * full.refs
+
+
 # --------------------------------------------------------------------------
 # Zoo entries flow through the standard pipeline and match their pins.
 # --------------------------------------------------------------------------
