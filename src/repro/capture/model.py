@@ -188,7 +188,8 @@ class ModelCapture:
                     obs.count("capture.model.stream_blocks")
                     yield addr
             return
-        counts, window = self._window(target_refs, center)
+        with obs.span("capture.model.window", model=self.name):
+            counts, window = self._window(target_refs, center)
         if window is None:
             yield from self.walk_stream()
             return
@@ -211,11 +212,15 @@ class ModelCapture:
 
     def _window_blocks(self, counts, start: int, end: int):
         """Each op's slice of refs ``[start, end)`` of the step, in program
-        order; an op the window cuts emits only its slice."""
+        order; an op the window cuts emits only its slice.  Counts the ops
+        the window overlaps (``capture.model.window_ops``)."""
+        from repro import obs
+
         pos = 0
         for op, r in zip(self.ops, counts):
             nxt = pos + r.refs
             if nxt > start and pos < end:
+                obs.count("capture.model.window_ops")
                 blk = op.walk(span=(max(0, start - pos),
                                     min(r.refs, end - pos))).addresses
                 if blk.size:
@@ -239,10 +244,12 @@ class ModelCapture:
         rata; ``flops`` stays the whole-step count so AI must be taken
         against the whole-step ``refs``, not the window length.
         """
-        counts, window = self._window(target_refs, center)
+        from repro import obs
+
+        with obs.span("capture.model.window", model=self.name):
+            counts, window = self._window(target_refs, center)
         if window is None:
             return self.walk()
-        from repro import obs
 
         obs.count("capture.model.concat")  # windowed traces materialize too
         addr = np.concatenate(list(self._window_blocks(counts, *window)))

@@ -12,6 +12,7 @@ from ..models.config import ModelConfig, SHAPES, ShapeSpec  # noqa: F401
 from . import (
     deepseek_moe_16b,
     deepseek_v2_lite_16b,
+    deepseek_v2,
     qwen2_5_14b,
     phi4_mini_3_8b,
     nemotron_4_340b,
@@ -33,6 +34,7 @@ _MODULES = {
     "mamba2-780m": mamba2_780m,
     "whisper-large-v3": whisper_large_v3,
     "paligemma-3b": paligemma_3b,
+    "deepseek-v2": deepseek_v2,
 }
 
 ARCHS = tuple(_MODULES)

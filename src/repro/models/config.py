@@ -32,18 +32,30 @@ class ModelConfig:
     mlp_kind: str = "swiglu"       # swiglu | relu2 | gelu
     norm_eps: float = 1e-6
     rope_theta: float = 10_000.0
+    # YaRN, as DeepSeek-V2's config states it (factor, mscale, mscale_all_dim,
+    # beta_fast, beta_slow, original_max_position_embeddings); None -> off
+    rope_scaling: dict | None = field(default=None, hash=False)
     tie_embeddings: bool = False
 
     # MoE
-    n_routed_experts: int = 0
+    n_routed_experts: int = 0      # routed experts held here (this chip's)
     n_shared_experts: int = 0
     top_k: int = 0
     d_ff_expert: int = 0           # per-expert intermediate size
     router_aux_coef: float = 0.01
     moe_capacity_factor: float = 1.25   # train default; serving uses higher
+    expert_capacity: int = 0       # tokens a held expert takes; 0 -> factor
+    n_router_experts: int = 0      # router outputs; 0 -> n_routed_experts
+    first_held_expert: int = 0     # held: [first, first + n_routed_experts)
+    n_group: int = 0               # group-limited routing: groups (0 -> off)
+    topk_group: int = 0            # groups a token's experts are taken from
+    norm_topk_prob: bool = True    # renormalize the top-k gates to sum 1
+    routed_scaling_factor: float = 1.0
+    first_dense_layers: int = 0    # leading layers with a d_ff MLP, not MoE
 
     # MLA (DeepSeek-V2)
     kv_lora_rank: int = 0          # 0 -> standard GQA attention
+    q_lora_rank: int = 0           # 0 -> query projected directly
     rope_head_dim: int = 64
     nope_head_dim: int = 128
     v_head_dim: int = 128
@@ -84,6 +96,12 @@ class ModelConfig:
         return self.n_routed_experts > 0
 
     @property
+    def router_experts(self) -> int:
+        """The router's outputs: every routed expert of the model, of which
+        this chip holds ``n_routed_experts``."""
+        return self.n_router_experts or self.n_routed_experts
+
+    @property
     def is_ssm(self) -> bool:
         return self.family == "ssm"
 
@@ -116,7 +134,10 @@ class ModelConfig:
 
         def attn_params() -> int:
             if self.kv_lora_rank:
-                q = d * self.n_heads * (self.nope_head_dim + self.rope_head_dim)
+                q_head = self.nope_head_dim + self.rope_head_dim
+                q = d * self.n_heads * q_head
+                if self.q_lora_rank:
+                    q = (d + self.n_heads * q_head) * self.q_lora_rank
                 kv_a = d * (self.kv_lora_rank + self.rope_head_dim)
                 kv_b = self.kv_lora_rank * self.n_heads * (
                     self.nope_head_dim + self.v_head_dim
@@ -136,7 +157,7 @@ class ModelConfig:
             e_ff = self.d_ff_expert or self.d_ff
             routed = self.n_routed_experts * 3 * d * e_ff
             shared = self.n_shared_experts * 3 * d * e_ff
-            router = d * self.n_routed_experts
+            router = d * self.router_experts
             return routed + shared + router
 
         def ssm_params() -> int:
@@ -152,7 +173,9 @@ class ModelConfig:
         if self.family in ("dense", "vlm"):
             total += self.n_layers * (attn_params() + mlp_params())
         elif self.family == "moe":
-            total += self.n_layers * (attn_params() + moe_params())
+            dense = self.first_dense_layers
+            total += self.n_layers * attn_params() + dense * mlp_params()
+            total += (self.n_layers - dense) * moe_params()
         elif self.family == "ssm":
             total += self.n_layers * ssm_params()
         elif self.family == "hybrid":
@@ -171,8 +194,10 @@ class ModelConfig:
         if not self.is_moe:
             return self.param_count()
         e_ff = self.d_ff_expert or self.d_ff
-        inactive = (self.n_routed_experts - self.top_k) * 3 * self.d_model * e_ff
-        return self.param_count() - self.n_layers * inactive
+        inactive = (max(0, self.n_routed_experts - self.top_k) * 3
+                    * self.d_model * e_ff)
+        moe_layers = self.n_layers - self.first_dense_layers
+        return self.param_count() - moe_layers * inactive
 
     def model_flops(self, tokens: int, *, training: bool = True) -> float:
         """6·N_active·D (plus attention quadratic term is ignored, matching

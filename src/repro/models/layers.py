@@ -15,6 +15,7 @@ Attention implementations:
 
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import Any
 
@@ -64,14 +65,51 @@ def rms_norm(x: jax.Array, p: Params, eps: float = 1e-6) -> jax.Array:
 # --------------------------------------------------------------------------
 # RoPE (rotate-half convention)
 # --------------------------------------------------------------------------
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: [..., S, H, D] (D even); positions: broadcastable to [..., S]."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature for a context stretched ``factor``
+    times (DeepSeek-V2's ``yarn_get_mscale``)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def _yarn(freq: np.ndarray, d: int, theta: float,
+          scaling: dict) -> tuple[np.ndarray, float]:
+    """YaRN (arXiv:2309.00071, as DeepSeek-V2 applies it): frequencies
+    that turn fewer than ``beta_slow`` times over the original context are
+    divided by ``factor``, those that turn more than ``beta_fast`` times
+    are kept, with a linear ramp between; and cos/sin are scaled by the
+    ratio of the two mscales."""
+    factor = scaling["factor"]
+    orig = scaling["original_max_position_embeddings"]
+
+    def dim_of(rotations: float) -> float:
+        return (d * math.log(orig / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(dim_of(scaling["beta_fast"])), 0)
+    high = min(math.ceil(dim_of(scaling["beta_slow"])), d - 1)
+    ramp = np.clip((np.arange(d // 2, dtype=np.float32) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    freq = freq / factor * ramp + freq * (1.0 - ramp)
+    mscale = (yarn_mscale(factor, scaling.get("mscale", 1.0))
+              / yarn_mscale(factor, scaling.get("mscale_all_dim", 1.0)))
+    return freq.astype(np.float32), mscale
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               scaling: dict | None = None) -> jax.Array:
+    """x: [..., S, H, D] (D even); positions: broadcastable to [..., S];
+    ``scaling``: a YaRN ``rope_scaling`` group, or None."""
     d = x.shape[-1]
     half = d // 2
     freq = theta ** (-np.arange(0, half, dtype=np.float32) / half)
+    mscale = 1.0
+    if scaling:
+        freq, mscale = _yarn(freq, d, theta, scaling)
     angles = positions.astype(jnp.float32)[..., None] * freq  # [..., S, half]
     angles = angles[..., None, :]                             # [..., S, 1, half]
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
@@ -294,37 +332,67 @@ def mla_init(key, cfg: ModelConfig) -> Params:
     dn, dr, dv = cfg.nope_head_dim, cfg.rope_head_dim, cfg.v_head_dim
     ks = jax.random.split(key, 5)
     s = d ** -0.5
-    return {
+    p: Params = {
         "wq": _init(ks[0], (d, h, dn + dr), s),
         "wkv_a": _init(ks[1], (d, r + dr), s),
         "kv_norm": jnp.ones((r,), jnp.float32),
         "wkv_b": _init(ks[2], (r, h, dn + dv), r ** -0.5),
         "wo": _init(ks[3], (h, dv, d), (h * dv) ** -0.5),
     }
+    if cfg.q_lora_rank:
+        # the query through a low-rank bottleneck: wq_a, RMSNorm, wq_b
+        rq = cfg.q_lora_rank
+        del p["wq"]
+        p["wq_a"] = _init(ks[0], (d, rq), s)
+        p["q_norm"] = jnp.ones((rq,), jnp.float32)
+        p["wq_b"] = _init(ks[4], (rq, h, dn + dr), rq ** -0.5)
+    return p
 
 
 def mla_axes(cfg: ModelConfig) -> Params:
-    return {
+    p: Params = {
         "wq": ("fsdp", "heads", None),
         "wkv_a": ("fsdp", "kv_lora"),
         "kv_norm": ("kv_lora",),
         "wkv_b": ("kv_lora", "heads", None),
         "wo": ("heads", None, "fsdp"),
     }
+    if cfg.q_lora_rank:
+        del p["wq"]
+        p["wq_a"] = ("fsdp", "q_lora")
+        p["q_norm"] = ("q_lora",)
+        p["wq_b"] = ("q_lora", "heads", None)
+    return p
+
+
+def _mla_scale(cfg: ModelConfig) -> float:
+    """The softmax scale: 1/sqrt(query head size), times YaRN's mscale
+    squared where the rope scaling states ``mscale_all_dim``."""
+    scale = (cfg.nope_head_dim + cfg.rope_head_dim) ** -0.5
+    yarn = cfg.rope_scaling or {}
+    if yarn.get("mscale_all_dim"):
+        scale *= yarn_mscale(yarn["factor"], yarn["mscale_all_dim"]) ** 2
+    return scale
 
 
 def _mla_project(p: Params, cfg: ModelConfig, x, positions):
     dt = _dtype(cfg)
     dn, dr = cfg.nope_head_dim, cfg.rope_head_dim
     r = cfg.kv_lora_rank
-    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(dt))
+    if cfg.q_lora_rank:
+        c_q = jnp.einsum("bsd,dr->bsr", x, p["wq_a"].astype(dt))
+        c_q = rms_norm(c_q, {"scale": p["q_norm"]}, cfg.norm_eps)
+        q = jnp.einsum("bsr,rhk->bshk", c_q, p["wq_b"].astype(dt))
+    else:
+        q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(dt))
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, cfg.rope_scaling)
 
     kv_a = jnp.einsum("bsd,dr->bsr", x, p["wkv_a"].astype(dt))
     c_kv, k_rope = kv_a[..., :r], kv_a[..., r:]
     c_kv = rms_norm(c_kv, {"scale": p["kv_norm"]}, cfg.norm_eps)
-    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta)
+    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta,
+                        cfg.rope_scaling)
     return q_nope, q_rope, c_kv, k_rope[:, :, 0, :]
 
 
@@ -340,8 +408,7 @@ def _mla_attend(p: Params, cfg: ModelConfig, q_nope, q_rope, c_kv, k_rope,
     q_lat = jnp.einsum("bshk,rhk->bshr", q_nope, wk_b)
     s1 = jnp.einsum("bshr,btr->bhst", q_lat, c_kv)
     s2 = jnp.einsum("bshk,btk->bhst", q_rope, k_rope)
-    scale = (dn + cfg.rope_head_dim) ** -0.5
-    scores = (s1 + s2).astype(jnp.float32) * scale
+    scores = (s1 + s2).astype(jnp.float32) * _mla_scale(cfg)
     sq, sk = scores.shape[2], scores.shape[3]
     if causal:
         qpos = q_offset + jnp.arange(sq)
@@ -378,7 +445,7 @@ def mla_fwd(p: Params, cfg: ModelConfig, x, positions, *, causal=True,
                                 k_rope.shape[:2] + (h, cfg.rope_head_dim))
     q = jnp.concatenate([q_nope, q_rope], axis=-1)      # [b,s,h,dn+dr]
     k = jnp.concatenate([k_nope, k_rope_h], axis=-1)
-    scale = (dn + cfg.rope_head_dim) ** -0.5
+    scale = _mla_scale(cfg)
     if cfg.attn_impl == "chunked":
         out = _sdpa_chunked(q, k, v, causal=causal, scale=scale,
                             chunk=cfg.attn_chunk)

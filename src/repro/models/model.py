@@ -4,7 +4,9 @@ One :class:`LM` wraps config-driven blocks:
 
 - ``dense``  — [attn + MLP] x L decoder (qwen2.5, phi4-mini, nemotron-4,
   granite; granite is MQA via n_kv_heads=1, nemotron uses squared-ReLU).
-- ``moe``    — [attn|MLA + fine-grained MoE] x L (deepseek-moe, deepseek-v2-lite).
+- ``moe``    — [attn|MLA + fine-grained MoE] x L (deepseek-moe, deepseek-v2-lite,
+  deepseek-v2), behind ``first_dense_layers`` [attn|MLA + MLP] layers
+  (deepseek-v2's first layer) scanned as a stack of their own.
 - ``ssm``    — [Mamba2/SSD] x L, attention-free (mamba2-780m).
 - ``hybrid`` — Zamba2: groups of SSM blocks with ONE shared attention+MLP
   block applied between groups (weight reuse across its applications).
@@ -67,36 +69,49 @@ class LM:
         cfg = self.cfg
         return L.mla_axes(cfg) if cfg.kv_lora_rank else L.attention_axes(cfg)
 
-    def _mixer_init(self, key):
+    def _mixer_init(self, key, dense: bool = False):
         cfg = self.cfg
-        if cfg.is_moe:
+        if cfg.is_moe and not dense:
             return M.moe_init(key, cfg)
         return L.mlp_init(key, cfg)
 
-    def _mixer_axes(self):
+    def _mixer_axes(self, dense: bool = False):
         cfg = self.cfg
-        return M.moe_axes(cfg) if cfg.is_moe else L.mlp_axes(cfg)
+        if cfg.is_moe and not dense:
+            return M.moe_axes(cfg)
+        return L.mlp_axes(cfg)
 
-    def _tf_layer_init(self, key, *, cross: bool = False):
+    def _tf_stacks(self) -> tuple[tuple[str, str, bool, int], ...]:
+        """The scanned stacks of transformer layers in order, each as
+        (params key, cache key, dense, layers): an MoE model's leading
+        dense layers, then the main stack."""
+        cfg = self.cfg
+        k = cfg.first_dense_layers if cfg.is_moe else 0
+        main = ("layers", "attn", False, cfg.n_layers - k)
+        return (("dense_layers", "dense_attn", True, k), main) if k \
+            else (main,)
+
+    def _tf_layer_init(self, key, *, cross: bool = False,
+                       dense: bool = False):
         cfg = self.cfg
         ks = jax.random.split(key, 6)
         p = {
             "ln1": L.rms_norm_init(cfg.d_model),
             "attn": self._attn_init(ks[0]),
             "ln2": L.rms_norm_init(cfg.d_model),
-            "mixer": self._mixer_init(ks[1]),
+            "mixer": self._mixer_init(ks[1], dense),
         }
         if cross:
             p["ln_x"] = L.rms_norm_init(cfg.d_model)
             p["xattn"] = L.attention_init(ks[2], cfg)
         return p
 
-    def _tf_layer_axes(self, *, cross: bool = False):
+    def _tf_layer_axes(self, *, cross: bool = False, dense: bool = False):
         p = {
             "ln1": L.rms_norm_axes(),
             "attn": self._attn_axes(),
             "ln2": L.rms_norm_axes(),
-            "mixer": self._mixer_axes(),
+            "mixer": self._mixer_axes(dense),
         }
         if cross:
             p["ln_x"] = L.rms_norm_axes()
@@ -104,7 +119,7 @@ class LM:
         return p
 
     def _tf_layer_fwd(self, p, x, positions, *, causal=True, aux=None,
-                      cross_kv=None, return_kv=False):
+                      cross_kv=None, return_kv=False, dense=False):
         from .sharding import constrain
         cfg = self.cfg
         kv = None
@@ -137,7 +152,7 @@ class LM:
             x = x + L.attention_fwd(p["xattn"], cfg, h, positions,
                                     causal=False, kv_override=cross_kv)
         h = L.rms_norm(x, p["ln2"], cfg.norm_eps)
-        if cfg.is_moe:
+        if cfg.is_moe and not dense:
             y, a = M.moe_fwd(p["mixer"], cfg, h)
             x = x + y
             aux = (aux + a) if aux is not None else a
@@ -194,7 +209,9 @@ class LM:
                          * cfg.d_model ** -0.5).astype(jnp.float32)
 
         if cfg.family in ("dense", "moe", "vlm"):
-            p["layers"] = _stack_init(self._tf_layer_init, ks[2], cfg.n_layers)
+            for key, (pk, _, dense, n) in zip(ks[2:], self._tf_stacks()):
+                p[pk] = _stack_init(partial(self._tf_layer_init, dense=dense),
+                                    key, n)
         elif cfg.family == "ssm":
             p["layers"] = _stack_init(self._ssm_layer_init, ks[2], cfg.n_layers)
         elif cfg.family == "hybrid":
@@ -226,7 +243,8 @@ class LM:
         if not cfg.tie_embeddings:
             p["head"] = ("fsdp", "vocab")
         if cfg.family in ("dense", "moe", "vlm"):
-            p["layers"] = _stack_axes(self._tf_layer_axes())
+            for pk, _, dense, _ in self._tf_stacks():
+                p[pk] = _stack_axes(self._tf_layer_axes(dense=dense))
         elif cfg.family == "ssm":
             p["layers"] = _stack_axes(self._ssm_layer_axes())
         elif cfg.family == "hybrid":
@@ -293,13 +311,15 @@ class LM:
         positions = jnp.arange(x.shape[1])[None, :]
 
         if cfg.family in ("dense", "moe", "vlm"):
-            def body(carry, lp):
-                h, a = carry
-                h, a = self._tf_layer_fwd(lp, h, positions, aux=a)
-                return (h, a), None
-            (x, aux), _ = jax.lax.scan(
-                self._maybe_remat(body), (x, aux), params["layers"]
-            )
+            for pk, _, dense, _ in self._tf_stacks():
+                def body(carry, lp, dense=dense):
+                    h, a = carry
+                    h, a = self._tf_layer_fwd(lp, h, positions, aux=a,
+                                              dense=dense)
+                    return (h, a), None
+                (x, aux), _ = jax.lax.scan(
+                    self._maybe_remat(body), (x, aux), params[pk]
+                )
         elif cfg.family == "ssm":
             def body(h, lp):
                 return self._ssm_layer_fwd(lp, h), None
@@ -405,7 +425,7 @@ class LM:
             }
 
         if cfg.family in ("dense", "moe", "vlm"):
-            return {"attn": attn_cache(cfg.n_layers)}
+            return {ck: attn_cache(n) for _, ck, _, n in self._tf_stacks()}
         if cfg.family == "ssm":
             return {"ssm": ssm_cache(cfg.n_layers)}
         if cfg.family == "hybrid":
@@ -454,7 +474,7 @@ class LM:
             }
 
         if cfg.family in ("dense", "moe", "vlm"):
-            return {"attn": attn_axes(True)}
+            return {ck: attn_axes(True) for _, ck, _, _ in self._tf_stacks()}
         if cfg.family == "ssm":
             return {"ssm": ssm_axes_()}
         if cfg.family == "hybrid":
@@ -510,13 +530,15 @@ class LM:
             next_pos = prompt_len.astype(jnp.int32) + offset
 
         if cfg.family in ("dense", "moe", "vlm"):
-            def body(carry, lp):
-                h, a = carry
-                h, a, kv = self._tf_layer_fwd(lp, h, positions, aux=a,
-                                              return_kv=True)
-                return (h, a), kv
-            (x, aux), kvs = jax.lax.scan(body, (x, aux), params["layers"])
-            new_cache = {"attn": jax.tree.map(write, cache["attn"], kvs)}
+            new_cache = {}
+            for pk, ck, dense, _ in self._tf_stacks():
+                def body(carry, lp, dense=dense):
+                    h, a = carry
+                    h, a, kv = self._tf_layer_fwd(lp, h, positions, aux=a,
+                                                  return_kv=True, dense=dense)
+                    return (h, a), kv
+                (x, aux), kvs = jax.lax.scan(body, (x, aux), params[pk])
+                new_cache[ck] = jax.tree.map(write, cache[ck], kvs)
         elif cfg.family == "ssm":
             def body(h, lp):
                 hh = L.rms_norm(h, lp["ln"], cfg.norm_eps)
@@ -582,7 +604,8 @@ class LM:
         logits = self._unembed(params, x_last)
         return logits, new_cache, next_pos
 
-    def _decode_tf_layer(self, p, cfg, x, cache, pos, cross_kv=None):
+    def _decode_tf_layer(self, p, cfg, x, cache, pos, cross_kv=None,
+                         dense=False):
         h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
         if cfg.kv_lora_rank:
             y, new_cache = L.mla_decode(p["attn"], cfg, h, cache, pos)
@@ -594,7 +617,7 @@ class LM:
             x = x + L.attention_fwd(p["xattn"], cfg, h, pos[:, None],
                                     causal=False, kv_override=cross_kv)
         h = L.rms_norm(x, p["ln2"], cfg.norm_eps)
-        if cfg.is_moe:
+        if cfg.is_moe and not dense:
             y, _ = M.moe_fwd(p["mixer"], cfg, h)
             x = x + y
         else:
@@ -607,15 +630,18 @@ class LM:
         x = self._embed(params, tokens)
 
         if cfg.family in ("dense", "moe", "vlm"):
-            def body(carry, inp):
-                h = carry
-                lp, lc = inp
-                h, new_c = self._decode_tf_layer(lp, cfg, h, lc, pos)
-                return h, new_c
-            x, new_cache = jax.lax.scan(
-                body, x, (params["layers"], cache["attn"])
-            )
-            cache = {"attn": new_cache}
+            new_cache = {}
+            for pk, ck, dense, _ in self._tf_stacks():
+                def body(carry, inp, dense=dense):
+                    h = carry
+                    lp, lc = inp
+                    h, new_c = self._decode_tf_layer(lp, cfg, h, lc, pos,
+                                                     dense=dense)
+                    return h, new_c
+                x, new_cache[ck] = jax.lax.scan(
+                    body, x, (params[pk], cache[ck])
+                )
+            cache = new_cache
         elif cfg.family == "ssm":
             def body(h, inp):
                 lp, lc = inp

@@ -49,6 +49,7 @@ DEFAULT_RULES: dict[str, Any] = {
     "experts": "model",
     "expert_ffn": None,
     "kv_lora": None,
+    "q_lora": None,
     "ssm_inner": "model",
     "ssm_heads": "model",
     "ssm_state": None,

@@ -346,6 +346,41 @@ def test_windowed_stream_is_slice_of_whole_walk(center):
         == want.tobytes()
 
 
+@pytest.mark.parametrize("center", [0.0, 0.5, 1.0])
+def test_window_placement_is_its_own_span_and_counts_its_ops(center,
+                                                             tmp_path):
+    """A windowed pass's placement (every op's count-only walk and the
+    window's bounds) is one ``capture.model.window`` span, closed before
+    any block is emitted, and ``capture.model.window_ops`` counts the ops
+    the window overlaps."""
+    from _obs_spans import bounds, span_events
+
+    from repro import obs
+
+    mc = _two_dots()
+    sizes = np.array([op.walk(count_only=True).refs for op in mc.ops])
+    target = int(sizes[1] + sizes[0] // 2)
+    start = int((sizes.sum() - target) * center)
+    ends = np.cumsum(sizes)
+    overlapped = int(np.count_nonzero((ends > start)
+                                      & (ends - sizes < start + target)))
+    obs.reset_counters()
+    obs.enable(tmp_path / "obs.jsonl")
+    try:
+        blocks = list(mc.walk_stream(target, center=center))
+        mc.walk_window(target, center=center)
+    finally:
+        obs.disable()
+    assert obs.counters()["capture.model.window_ops"] == 2 * overlapped
+    events = span_events(tmp_path / "obs.jsonl")
+    windows = [e for e in events if e["name"] == "capture.model.window"]
+    assert len(windows) == 2
+    first_emit = min(bounds(e)[0] for e in events
+                     if e["name"] == "capture.walk.emit")
+    assert bounds(windows[0])[1] <= first_emit
+    assert sum(b.size for b in blocks) == target
+
+
 def test_whole_walks_count_no_window():
     from repro import obs
 

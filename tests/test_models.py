@@ -178,8 +178,11 @@ def test_moe_balance_loss_signal():
 
 
 def test_param_count_analytic_matches_actual():
-    for arch in configs.ARCHS:
-        cfg = configs.get_smoke(arch)
+    # every smoke config, and deepseek-v2's with a chip's share of experts
+    held = configs.get_smoke("deepseek-v2").replace(
+        n_routed_experts=4, n_router_experts=16)
+    for arch, cfg in [(a, configs.get_smoke(a)) for a in configs.ARCHS] + [
+            ("deepseek-v2-held", held)]:
         lm = LM(cfg)
         shapes = jax.eval_shape(lm.init, KEY)
         actual = sum(int(np.prod(s.shape)) for s in jax.tree.leaves(shapes))
