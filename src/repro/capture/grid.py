@@ -170,24 +170,24 @@ def _tile_words(op: OperandSpec, block_idx: tuple[int, ...],
     return base_word + words
 
 
-def _tile_words_batch(op: OperandSpec, idxs: np.ndarray,
-                      base_word: int) -> np.ndarray:
-    """Word addresses for many blocks of one operand at once.
+def _tile_rows(op: OperandSpec, idxs: np.ndarray,
+               base_word: int) -> tuple[np.ndarray, int]:
+    """Word runs of many blocks of one operand at once.
 
-    ``idxs`` is ``(k, rank)``; row ``i`` of the result equals
-    ``_tile_words(op, tuple(idxs[i]), base_word)`` (shape ``(k,
-    block_words)``).
+    ``idxs`` is ``(k, rank)``.  Returns ``(starts, w)``: ``starts[i, r]``
+    is the first word address of row ``r`` (row-major over the block's
+    leading axes, ``R = prod(block_shape[:-1])`` rows) of block ``i``, and
+    each row is the ``w = block_shape[-1] // elems_per_word`` contiguous
+    words from its start, so ``(starts[i, :, None] + arange(w)).ravel()``
+    equals ``_tile_words(op, tuple(idxs[i]), base_word)``.  Every row
+    starts word-aligned (the array's last dim and the block's are whole
+    words), so the floor division runs once a row, not once an element.
     """
     shape, blk = op.shape, op.block_shape
     strides = [1] * len(shape)
     for i in range(len(shape) - 2, -1, -1):
         strides[i] = strides[i + 1] * shape[i + 1]
     k = idxs.shape[0]
-    starts = np.zeros((k, 1), dtype=np.int64)
-    for a in range(len(blk) - 1):
-        ax = np.arange(blk[a], dtype=np.int64) * strides[a]
-        offs = idxs[:, a, None] * (blk[a] * strides[a]) + ax[None, :]
-        starts = (starts[:, :, None] + offs[:, None, :]).reshape(k, -1)
     last_b = blk[-1]
     if last_b % op.elems_per_word:
         # With last_b word-aligned every block offset idx*last_b is too,
@@ -195,14 +195,13 @@ def _tile_words_batch(op: OperandSpec, idxs: np.ndarray,
         raise ValueError(
             f"{op.name}: block rows must be word-aligned "
             f"(last dim {last_b}, {op.elems_per_word} elems/word)")
-    row = np.arange(last_b, dtype=np.int64)
-    elems = (starts[:, :, None]
-             + (idxs[:, -1] * last_b)[:, None, None]
-             + row[None, None, :]).reshape(k, -1)
-    words = elems // op.elems_per_word
-    if op.elems_per_word > 1:
-        words = words[:, :: op.elems_per_word]
-    return base_word + words
+    starts = (idxs[:, -1] * last_b)[:, None]
+    for a in range(len(blk) - 1):
+        ax = np.arange(blk[a], dtype=np.int64) * strides[a]
+        offs = idxs[:, a, None] * (blk[a] * strides[a]) + ax[None, :]
+        starts = (starts[:, :, None] + offs[:, None, :]).reshape(k, -1)
+    w = last_b // op.elems_per_word
+    return base_word + starts // op.elems_per_word, w
 
 
 def from_jaxpr(fn, args, *, scalar_values=(), flops: float = 0.0,
@@ -403,8 +402,8 @@ def _walk(cap: GridCapture, *, count_only: bool,
     else:
         # nonzero on the transposed mask yields events in (step, operand)
         # lexicographic order — the scalar walker's emission order.  All
-        # of one operand's blocks tile in a single batched call, then land
-        # at their events' offsets in the output stream.
+        # of one operand's blocks tile in a single batched call as word
+        # runs, one per block row, and are placed at row granularity.
         with obs.span("capture.walk.emit"):
             si_arr, oi_arr = np.nonzero(emit.T)
             bw = np.array([_block_words(op) for op in cap.operands],
@@ -420,20 +419,33 @@ def _walk(cap: GridCapture, *, count_only: bool,
             sizes, ends = sizes[e0:e1], ends[e0:e1]
             first = int(ends[0] - sizes[0]) if ends.size else lo
             last = int(ends[-1]) if ends.size else lo
-            addr = np.empty(last - first, dtype=np.int64)
+            runs = []
             for oi, op in enumerate(cap.operands):
                 sel = np.flatnonzero(oi_arr == oi)
-                if not sel.size:
-                    continue
-                tiles = _tile_words_batch(op, tables[oi][si_arr[sel]],
-                                          base[op.name])
-                pos = ((ends[sel] - sizes[sel] - first)[:, None]
-                       + np.arange(tiles.shape[1], dtype=np.int64)[None, :])
-                addr[pos] = tiles
+                if sel.size:
+                    runs.append((op, sel, *_tile_rows(
+                        op, tables[oi][si_arr[sel]], base[op.name])))
+            # A block is R rows of w words, so every event starts a
+            # multiple of g, the row widths' gcd, after `first`: place the
+            # start of each g-word chunk, then expand all chunks at once.
+            g = math.gcd(*(w for *_, w in runs)) if runs else 1
+            chunks = np.empty((last - first) // g, dtype=np.int64)
+            rows = 0
+            for op, sel, starts, w in runs:
+                # a block's chunks are its rows' chunks, back to back
+                block = (starts[:, :, None]
+                         + np.arange(0, w, g)).reshape(sel.size, -1)
+                at = (ends[sel] - sizes[sel] - first) // g
+                chunks[at[:, None] + np.arange(block.shape[1])] = block
+                rows += starts.size
                 if op.role == "in":
-                    loads += tiles.size
+                    loads += starts.size * w
                 else:
-                    stores += tiles.size
+                    stores += starts.size * w
+            addr = np.empty(last - first, dtype=np.int64)
+            np.add(chunks[:, None], np.arange(g, dtype=np.int64),
+                   out=addr.reshape(-1, g))
+            obs.count("capture.walk.emit_runs", rows)
             if (first, last) != (lo, hi):
                 # the run's first and last blocks may stick out of the span
                 for e, out in ((0, lo - first), (-1, last - hi)):

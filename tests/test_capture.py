@@ -314,6 +314,144 @@ class TestWindowedEmission:
         assert "capture.walk.window_calls" not in c
 
 
+def _runs_rank1() -> GridCapture:
+    """Rank-1 operands, one element a word, rows of 64, 48 and 40 words."""
+    return GridCapture("runs.rank1", (32,), operands=(
+        OperandSpec("x", "in", (4096,), (64,), lambda i: (i,),
+                    elems_per_word=1),
+        OperandSpec("y", "in", (4096,), (48,), lambda i: (i % 5,),
+                    elems_per_word=1),
+        OperandSpec("o", "out", (2048,), (40,), lambda i: (i // 4,),
+                    elems_per_word=1),
+    ))
+
+
+def _runs_rank2() -> GridCapture:
+    """Rank-2 operands, two elements a word, rows of 3 and 2 words."""
+    return GridCapture("runs.rank2", (8, 6), operands=(
+        OperandSpec("a", "in", (64, 256), (8, 6), lambda i, j: (i, j)),
+        OperandSpec("b", "in", (32, 64), (4, 4), lambda i, j: (j, i)),
+        OperandSpec("o", "out", (64, 256), (8, 6), lambda i, j: (i, 0)),
+    ))
+
+
+def _runs_rank3() -> GridCapture:
+    """Rank-3 bf16 operands, rows of 32, 16 and 6 words."""
+    return GridCapture("runs.rank3", (2, 4, 4), operands=(
+        OperandSpec("x", "in", (2, 64, 512), (1, 8, 128),
+                    lambda b, i, k: (b, i, k), elems_per_word=4),
+        OperandSpec("y", "in", (2, 512, 64), (1, 16, 64),
+                    lambda b, i, k: (b, k, 0), elems_per_word=4),
+        OperandSpec("o", "out", (2, 64, 64), (1, 8, 24),
+                    lambda b, i, k: (b, i, 0), elems_per_word=4),
+    ))
+
+
+def _runs_rank4() -> GridCapture:
+    """Rank-4 bf16 operands, rows of 12 and 8 words."""
+    return GridCapture("runs.rank4", (2, 3, 4), operands=(
+        OperandSpec("x", "in", (2, 3, 32, 96), (1, 1, 8, 48),
+                    lambda a, b, c: (a, b, c, c % 2), elems_per_word=4),
+        OperandSpec("y", "in", (2, 4, 16, 64), (1, 2, 4, 32),
+                    lambda a, b, c: (a, 0, c, b % 2), elems_per_word=4),
+        OperandSpec("o", "out", (2, 3, 32, 96), (1, 1, 8, 48),
+                    lambda a, b, c: (a, b, 0, 1), elems_per_word=4),
+    ))
+
+
+def _runs_mixed() -> GridCapture:
+    """Ranks 1, 4 and 2 in one launch, rows of 5, 8 and 7 words."""
+    return GridCapture("runs.mixed", (4, 8), operands=(
+        OperandSpec("s", "in", (512,), (10,), lambda i, j: (j,)),
+        OperandSpec("t", "in", (2, 2, 16, 64), (1, 1, 4, 16),
+                    lambda i, j: (i % 2, i // 2, j % 4, j // 4)),
+        OperandSpec("o", "out", (8, 64), (2, 14), lambda i, j: (i, 0)),
+    ))
+
+
+# geometry -> (builder, gcd of its operands' row widths in words)
+_RUN_GEOMETRIES = {
+    "rank1.epw1.g8": (_runs_rank1, 8),
+    "rank2.epw2.g1": (_runs_rank2, 1),
+    "rank3.epw4.g2": (_runs_rank3, 2),
+    "rank4.epw4.g4": (_runs_rank4, 4),
+    "mixed.epw2.g1": (_runs_mixed, 1),
+}
+_RUN_SPANS = {
+    "whole": lambda n: (0, n),
+    "cut_first_and_last": lambda n: (3, n - 3),
+    "middle_third": lambda n: (n // 3 + 1, 2 * n // 3 + 1),
+    "inside_one_block": lambda n: (n // 2 + 1, n // 2 + 2),
+}
+
+
+class TestWordRuns:
+    """The vectorized walk emits each block as word runs, one per block
+    row, and places them at the granularity of the row widths' gcd:
+    byte-identical to the scalar walker and to the full walk's slice for
+    any word size, rank and mix of row widths."""
+
+    @pytest.mark.parametrize("span", list(_RUN_SPANS))
+    @pytest.mark.parametrize("geometry", list(_RUN_GEOMETRIES))
+    def test_window_matches_loop_and_full_slice(self, geometry, span):
+        from repro.capture.grid import _walk_loop
+
+        build, g = _RUN_GEOMETRIES[geometry]
+        cap = build()
+        assert np.prod(cap.grid) * len(cap.operands) > 64   # vectorized
+        widths = [op.block_shape[-1] // op.elems_per_word
+                  for op in cap.operands]
+        assert np.gcd.reduce(widths) == g < min(widths)
+        full = walk(cap)
+        ref_full = _walk_loop(cap, count_only=False, bases=None)
+        assert full.addresses.tobytes() == ref_full.addresses.tobytes()
+        assert (full.loads, full.stores) == (ref_full.loads, ref_full.stores)
+        lo, hi = _RUN_SPANS[span](full.refs)
+        got = walk(cap, span=(lo, hi))
+        ref = _walk_loop(cap, count_only=False, bases=None, span=(lo, hi))
+        assert got.addresses.tobytes() == ref.addresses.tobytes()
+        assert got.addresses.tobytes() == full.addresses[lo:hi].tobytes()
+        assert (got.loads, got.stores) == (ref.loads, ref.stores)
+        assert got.refs == hi - lo
+
+    def test_unaligned_block_row_rejected(self):
+        cap = GridCapture("unaligned", (32,), operands=(
+            OperandSpec("x", "in", (4096,), (6,), lambda i: (i,),
+                        elems_per_word=4),
+            OperandSpec("o", "out", (4096,), (8,), lambda i: (i,),
+                        elems_per_word=4),
+            OperandSpec("y", "in", (4096,), (8,), lambda i: (i,),
+                        elems_per_word=4),
+        ))
+        with pytest.raises(ValueError, match="block rows must be word-aligned"):
+            walk(cap)
+
+    @pytest.mark.parametrize("kind", ["whole", "window", "inside_block"])
+    def test_emit_runs_counts_block_rows_placed(self, kind):
+        from repro import obs
+
+        cap = _qwen_ffn_dot()
+        # Its schedule: every step fetches lhs (64 rows) and rhs (128
+        # rows); the last k step of each output column writes back out
+        # (64 rows).  Every row is 128 bf16 elements, 32 words.
+        rows = np.array([r for _ in range(12) for kk in range(8)
+                         for r in (64, 128) + ((64,) if kk == 7 else ())])
+        ends = np.cumsum(rows * 32)
+        starts = ends - rows * 32
+        assert ends[-1] == walk(cap).refs
+        lo, hi = {"whole": (0, int(ends[-1])),
+                  "window": (int(ends[3]) - 5, int(ends[40]) + 7),
+                  "inside_block": (int(starts[10]) + 1,
+                                   int(starts[10]) + 2)}[kind]
+        obs.reset_counters()
+        got = walk(cap) if kind == "whole" else walk(cap, span=(lo, hi))
+        placed = int(rows[(starts < hi) & (ends > lo)].sum())
+        assert obs.counters()["capture.walk.emit_runs"] == placed
+        assert got.refs == hi - lo
+        if kind == "whole":
+            assert placed == 12 * (8 * (64 + 128) + 64)
+
+
 # --------------------------------------------------------------------------
 # Captured workloads (the suite's `captured` source)
 # --------------------------------------------------------------------------
