@@ -1,53 +1,15 @@
-"""CI perf-regression gate over the ``benchmarks.run`` section record.
+"""Structural counter gates over a ``repro.obs`` trace.
 
-``benchmarks.run`` writes a machine-readable perf record (per-section
-wall-clock, bucketed per configuration) to ``BENCH.json``; the
-repository commits one as the performance baseline.  ``timing_smoke``
-gates only single-cell simulation latency, so a regression in the *batch*
-paths (engine batching, suite runner, figure queries) used to be
-invisible to CI.  This gate closes that hole::
+Speed is measured on the chip by ``bench/``; this gate checks what a
+trace can show exactly and deterministically: that a path kept its
+sharing structure.  Given ``--obs-trace TRACE.jsonl`` (a ``repro.obs``
+trace, recorded via ``--trace`` on the suite CLI) it asserts *counter
+invariants*::
 
-    python -m benchmarks.run --fast --bench-json bench-ci.json
-    python -m benchmarks.perf_gate --current bench-ci.json
-
-compares every section's wall-clock in the fresh record against the
-committed baseline under the same configuration bucket and exits non-zero
-when any section regressed by more than ``--max-ratio`` (default 2.0 —
-wide enough to absorb runner variance, tight enough to catch an
-accidentally-serialized batch path).  Sections faster than
-``--min-seconds`` in the baseline are compared against that floor instead
-(timer noise on a 0.0 s section is not a regression signal); sections
-present on only one side are reported but never fail the gate (new or
-renamed sections should not need a baseline edit in the same commit).
-The ``serving_warm`` section — pure content-addressed store recall of the
-serving roster — is expected to sit under the noise floor; it is gated by
-the ``--min-seconds`` floor rather than its own (near-zero) baseline, so
-only a recall path that has become genuinely slow (seconds, not
-milliseconds) trips it.
-
-The committed baseline encodes the wall-clock of the machine that
-recorded it; to keep the gate meaningful on a runner of different speed,
-``benchmarks.run`` also records a fixed NumPy calibration workload's
-wall-clock (``meta.calibration_seconds``) and the gate scales the
-baseline by the measured speed ratio when both records carry it (capped
-to [1/4, 4] so a corrupt calibration cannot neuter the gate).  Sections
-that time *real kernel* wall-clock (``kernels_stream`` /
-``kernels_attention`` measure achieved GB/s of jitted Pallas kernels)
-are jit-noise-bound rather than simulator-bound — CI skips them via
-``--skip``.
-
-Structural counter gates (``--obs-trace``)
-------------------------------------------
-Wall-clock ratios catch a path that got *slow*; they cannot catch a path
-that silently lost its sharing structure while staying (barely) inside
-the envelope.  With ``--obs-trace TRACE.jsonl`` (a ``repro.obs`` trace,
-recorded via ``--trace`` on the suite CLI) the gate additionally asserts
-*counter invariants*::
-
-    # cold roster: every profile pass goes through the trace memo —
-    # one StreamProfile scan per unique geometry, never more
+    # cold roster: every profile pass goes through the trace memo, and
+    # the segmented walk may serve several geometries from one scan
     python -m benchmarks.perf_gate --obs-trace cold.jsonl \
-        --obs-require profile.scan==profile.geom
+        --obs-require 'profile.scan<=profile.geom'
 
     # warm rerun: pure store recall — zero cold recalls, zero sims
     python -m benchmarks.perf_gate --obs-trace warm.jsonl \
@@ -63,114 +25,15 @@ recorded via ``--trace`` on the suite CLI) the gate additionally asserts
 value, missing counters read as 0; ``span:NAME`` resolves to that span's
 total seconds).  ``--obs-min-coverage A+B=F`` requires the summed span
 totals of ``A``/``B`` to cover at least fraction ``F`` of the trace's
-end-to-end wall — the ROADMAP item 3 target ("one profile pass per
-unique geometry", "roster bounded by recall") expressed as a regression
-gate instead of a hope.  With ``--obs-trace`` given, ``--current`` is
-optional, so CI can run the counter gate on a trace alone.
+end-to-end wall.  The gate exits 1 when any check is violated.
 """
 
 from __future__ import annotations
 
 import argparse
-import json
 import operator
 import sys
 
-DEFAULT_BASELINE = "BENCH.json"
-# The perf record lived at BENCH_PR4.json before it became rolling; both
-# spellings load (with a stderr note) so older branches/scripts keep
-# working.
-_BASELINE_ALIASES = {"BENCH.json": "BENCH_PR4.json",
-                     "BENCH_PR4.json": "BENCH.json"}
-DEFAULT_CONFIG = "fast-refs20000-vectorized"
-
-
-def load_sections(path: str, config: str) -> dict[str, float]:
-    return _load_bucket(path, config)[0]
-
-
-def _open_record(path: str) -> dict:
-    try:
-        with open(path) as f:
-            return json.load(f)
-    except FileNotFoundError:
-        alias = _BASELINE_ALIASES.get(path)
-        if alias is None:
-            raise
-        try:
-            with open(alias) as f:
-                record = json.load(f)
-        except FileNotFoundError:
-            raise SystemExit(
-                f"{path}: not found (nor its former name {alias})")
-        print(f"# perf_gate: {path} not found; loaded {alias} "
-              f"(renamed baseline)", file=sys.stderr)
-        return record
-
-
-def _load_bucket(path: str, config: str) -> tuple[dict[str, float], float]:
-    """(per-section seconds, meta calibration seconds or 0.0)."""
-    record = _open_record(path)
-    bucket = record.get("runs", {}).get(config)
-    if bucket is None:
-        raise SystemExit(
-            f"{path}: no '{config}' bucket under 'runs' "
-            f"(have: {sorted(record.get('runs', {}))})")
-    sections = {name: float(entry["seconds"])
-                for name, entry in bucket.get("sections", {}).items()}
-    cal = float(bucket.get("meta", {}).get("calibration_seconds", 0.0))
-    return sections, cal
-
-
-def speed_factor(base_cal: float, cur_cal: float) -> float:
-    """Baseline scaling for machine-speed difference, capped to [1/4, 4].
-
-    > 1 means the current machine is slower than the recording machine,
-    so baseline seconds are inflated before comparison.  0/absent
-    calibration on either side disables normalization (factor 1.0).
-    """
-    if base_cal <= 0.0 or cur_cal <= 0.0:
-        return 1.0
-    return min(4.0, max(0.25, cur_cal / base_cal))
-
-
-def gate(baseline: dict[str, float], current: dict[str, float], *,
-         max_ratio: float, min_seconds: float, factor: float = 1.0,
-         out=sys.stdout) -> list[str]:
-    """Compare per-section wall-clock; return the failing section names.
-
-    ``factor`` scales the baseline for machine-speed difference (see
-    :func:`speed_factor`) before the ratio test.
-    """
-    failures: list[str] = []
-    if factor != 1.0:
-        print(f"machine-speed normalization: baseline x {factor:.2f}",
-              file=out)
-    print(f"{'section':18s} {'base_s':>8s} {'now_s':>8s} {'ratio':>7s}",
-          file=out)
-    for name in sorted(set(baseline) | set(current)):
-        if name not in current:
-            print(f"{name:18s} {baseline[name]:8.2f} {'-':>8s} {'-':>7s}  "
-                  f"(absent from current run)", file=out)
-            continue
-        if name not in baseline:
-            print(f"{name:18s} {'-':>8s} {current[name]:8.2f} {'-':>7s}  "
-                  f"(no baseline; informational)", file=out)
-            continue
-        floor = max(baseline[name] * factor, min_seconds)
-        ratio = current[name] / floor
-        verdict = ""
-        if current[name] > max_ratio * floor:
-            failures.append(name)
-            verdict = f"  REGRESSION (> {max_ratio:g}x)"
-        print(f"{name:18s} {baseline[name]:8.2f} {current[name]:8.2f} "
-              f"{ratio:7.2f}{verdict}", file=out)
-    return failures
-
-
-# --------------------------------------------------------------------------
-# Structural counter gates over a repro.obs trace
-# --------------------------------------------------------------------------
 _OBS_OPS = {
     "==": operator.eq, "!=": operator.ne, "<=": operator.le,
     ">=": operator.ge, "<": operator.lt, ">": operator.gt,
@@ -202,13 +65,15 @@ def _resolve(rep, token: str) -> float:
 
 
 def obs_gate(rep, requires: list[str], coverages: list[str], *,
-             out=sys.stdout) -> list[str]:
+             out=None) -> list[str]:
     """Check counter invariants + span coverage; return failed checks.
 
     ``rep`` is a :class:`repro.obs.report.ObsReport`; ``requires`` are
     raw ``--obs-require`` expressions, ``coverages`` raw
-    ``--obs-min-coverage`` specs (``NAME[+NAME...]=FRACTION``).
+    ``--obs-min-coverage`` specs (``NAME[+NAME...]=FRACTION``).  The
+    verdicts print to ``out`` (default: the current ``sys.stdout``).
     """
+    out = sys.stdout if out is None else out
     failures: list[str] = []
     for expr in requires:
         lhs, op, rhs = parse_require(expr)
@@ -243,86 +108,34 @@ def obs_gate(rep, requires: list[str], coverages: list[str], *,
 def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(
         prog="python -m benchmarks.perf_gate",
-        description="fail CI when a benchmarks.run section's wall-clock "
-                    "regresses vs the committed perf record")
-    ap.add_argument("--baseline", default=DEFAULT_BASELINE,
-                    help=f"committed perf record (default {DEFAULT_BASELINE}; "
-                         "the former BENCH_PR4.json name still loads)")
-    ap.add_argument("--current", default=None,
-                    help="perf record written by the CI benchmarks.run "
-                         "(required unless --obs-trace alone is gated)")
-    ap.add_argument("--config", default=DEFAULT_CONFIG,
-                    help=f"runs bucket to compare (default {DEFAULT_CONFIG})")
-    ap.add_argument("--max-ratio", type=float, default=2.0,
-                    help="fail when current > max-ratio * baseline "
-                         "(default 2.0)")
-    ap.add_argument("--min-seconds", type=float, default=0.75,
-                    help="baseline floor; faster baseline sections are "
-                         "compared against this instead (default 0.75)")
-    ap.add_argument("--skip", default="", metavar="S[,S]",
-                    help="comma list of sections to exclude (e.g. the "
-                         "machine-bound kernel wall-clock sections)")
-    ap.add_argument("--obs-trace", default=None, metavar="TRACE.jsonl",
+        description="fail CI when a repro.obs trace violates a counter "
+                    "invariant or span-coverage floor")
+    ap.add_argument("--obs-trace", required=True, metavar="TRACE.jsonl",
                     action="append",
                     help="repro.obs trace file(s) to merge and gate "
                          "counter invariants over (repeatable)")
     ap.add_argument("--obs-require", default=[], action="append",
                     metavar="EXPR",
                     help="counter invariant, e.g. store.recall.cold==0 "
-                         "or profile.scan<=profile.geom (repeatable; "
-                         "needs --obs-trace)")
+                         "or profile.scan<=profile.geom (repeatable)")
     ap.add_argument("--obs-min-coverage", default=[], action="append",
                     metavar="NAME[+NAME..]=FRACTION",
                     help="require the named spans' summed total to cover "
                          "at least FRACTION of the trace wall-clock "
-                         "(repeatable; needs --obs-trace)")
+                         "(repeatable)")
     args = ap.parse_args(argv)
 
-    if (args.obs_require or args.obs_min_coverage) and not args.obs_trace:
-        ap.error("--obs-require/--obs-min-coverage need --obs-trace")
-    if args.current is None and not args.obs_trace:
-        ap.error("--current is required (unless gating --obs-trace alone)")
+    from repro.obs.report import aggregate
 
-    failures: list[str] = []
-    current: dict[str, float] = {}
-    if args.current is not None:
-        skip = {s.strip() for s in args.skip.split(",") if s.strip()}
-        base_sections, base_cal = _load_bucket(args.baseline, args.config)
-        cur_sections, cur_cal = _load_bucket(args.current, args.config)
-        baseline = {k: v for k, v in base_sections.items() if k not in skip}
-        current = {k: v for k, v in cur_sections.items() if k not in skip}
-        failures += gate(baseline, current, max_ratio=args.max_ratio,
-                         min_seconds=args.min_seconds,
-                         factor=speed_factor(base_cal, cur_cal))
-
-    obs_failures: list[str] = []
-    if args.obs_trace:
-        from repro.obs.report import aggregate
-
-        rep = aggregate(args.obs_trace)
-        obs_failures = obs_gate(rep, args.obs_require,
-                                args.obs_min_coverage)
-        failures += obs_failures
-
+    rep = aggregate(args.obs_trace)
+    failures = obs_gate(rep, args.obs_require, args.obs_min_coverage)
     if failures:
-        wall = [f for f in failures if f not in obs_failures]
-        parts = []
-        if wall:
-            parts.append(f"{', '.join(wall)} regressed beyond "
-                         f"{args.max_ratio:g}x")
-        if obs_failures:
-            parts.append(f"counter invariant(s) violated: "
-                         f"{'; '.join(obs_failures)}")
-        print(f"perf gate FAILED: {'; '.join(parts)}", file=sys.stderr)
+        print(f"perf gate FAILED: counter invariant(s) violated: "
+              f"{'; '.join(failures)}", file=sys.stderr)
         return 1
-    checked = []
-    if args.current is not None:
-        checked.append(f"{len(current)} section(s) within "
-                       f"{args.max_ratio:g}x of baseline")
-    if args.obs_trace:
-        checked.append(f"{len(args.obs_require) + len(args.obs_min_coverage)}"
-                       f" counter invariant(s) hold")
-    print(f"perf gate OK: {'; '.join(checked)}", file=sys.stderr)
+    print(f"perf gate OK: "
+          f"{len(args.obs_require) + len(args.obs_min_coverage)}"
+          f" counter invariant(s) hold", file=sys.stderr)
     return 0
 
 

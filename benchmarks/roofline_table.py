@@ -1,37 +1,18 @@
 """§Roofline: the per-(arch x shape x mesh) three-term table.
 
-Reads the dry-run artifacts (results/dryrun/*.json).  Falls back to
-computing the analytic terms directly (no compile) when a cell artifact is
-missing, so `python -m benchmarks.run` works even without the 512-device
-dry-run having been executed in this checkout.
+Every row is computed from the analytic cost model (no compile) for the
+256-chip (16x16) and 512-chip (2x16x16) v5e pod meshes the cells model.
 """
 
 from __future__ import annotations
-
-import glob
-import json
-import os
 
 from repro import configs
 from repro.core import analytic, hlo_analysis
 from repro.launch.cells import all_cells
 
-RESULTS_DIR = os.environ.get("DRYRUN_DIR", "results/dryrun")
-
 HEADER = ("arch", "shape", "mesh", "t_compute_s", "t_memory_s",
           "t_collective_s", "dominant", "class", "mfu_bound",
           "useful_ratio", "roofline_fraction")
-
-
-def _from_artifacts() -> dict[tuple, dict]:
-    out = {}
-    for f in glob.glob(os.path.join(RESULTS_DIR, "*.json")):
-        if "_skips" in f:
-            continue
-        d = json.load(open(f))
-        if d.get("status") == "ok":
-            out[(d["arch"], d["shape"], d["mesh"])] = d
-    return out
 
 
 def _analytic_entry(plan, mesh_name: str) -> dict:
@@ -57,19 +38,16 @@ def _analytic_entry(plan, mesh_name: str) -> dict:
 
 
 def rows():
-    arts = _from_artifacts()
     out = []
     for plan in all_cells():
         for mesh_name in ("16x16", "2x16x16"):
-            d = arts.get((plan.arch, plan.shape.name, mesh_name))
-            if d is None:
-                d = _analytic_entry(plan, mesh_name)
+            d = _analytic_entry(plan, mesh_name)
             out.append((d["arch"], d["shape"], d["mesh"],
                         f"{d['t_compute_s']:.3e}", f"{d['t_memory_s']:.3e}",
                         f"{d['t_collective_s']:.3e}", d["dominant"],
                         d["class"], round(d["mfu_bound"], 3),
-                        round(d.get("useful_compute_ratio", 0.0), 3),
-                        round(d.get("roofline_fraction", 0.0), 3)))
+                        round(d["useful_compute_ratio"], 3),
+                        round(d["roofline_fraction"], 3)))
     # assignment-mandated skips, for table completeness
     for arch in configs.ARCHS:
         if "long_500k" not in configs.shapes_for(arch):

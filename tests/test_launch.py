@@ -1,9 +1,6 @@
 """Launch-layer tests: sharding resolution on production-shaped meshes,
-cell plans, analytic cost model sanity, and a miniature dry-run.
-
-The real 512-device dry-run needs XLA_FLAGS set before jax init, so it runs
-as its own process (results land in results/dryrun/); here we verify the
-machinery on the in-process device set.
+cell plans, analytic cost model sanity, and a miniature lower+compile of
+the cells on the in-process device set.
 """
 
 import jax
@@ -167,7 +164,7 @@ class TestHloAnalysis:
 @pytest.mark.slow
 class TestMiniDryrun:
     """End-to-end lower+compile on the in-process (1-device) mesh, smoke
-    configs — validates the same build_cell path the 512-way dry-run uses.
+    configs, through the build_cell path every cell plan lowers with.
     (~20 s of XLA compilation: slow-marked out of the fast local loop.)"""
 
     @pytest.mark.parametrize("arch", ["qwen2.5-14b", "deepseek-moe-16b",

@@ -1,158 +1,13 @@
-"""Tests for the CI perf-regression gate (benchmarks.perf_gate)."""
+"""Tests for the CI counter gate (benchmarks.perf_gate)."""
 
 import io
 import json
+import pathlib
+import re
 
 import pytest
 
 from benchmarks import perf_gate
-
-
-def _record(sections: dict[str, float]) -> dict:
-    return {"runs": {"cfg": {"sections": {
-        name: {"seconds": s, "rows": 1} for name, s in sections.items()
-    }}}}
-
-
-class TestGate:
-    def test_within_ratio_passes(self):
-        fails = perf_gate.gate({"a": 2.0, "b": 4.0}, {"a": 3.9, "b": 4.0},
-                               max_ratio=2.0, min_seconds=0.75,
-                               out=io.StringIO())
-        assert fails == []
-
-    def test_regression_fails(self):
-        fails = perf_gate.gate({"a": 2.0, "b": 1.0}, {"a": 4.1, "b": 1.0},
-                               max_ratio=2.0, min_seconds=0.75,
-                               out=io.StringIO())
-        assert fails == ["a"]
-
-    def test_fast_baseline_compared_against_floor(self):
-        # 0.0s baseline: 1.0s current is under 2 * 0.75 floor -> pass,
-        # 2.0s current is over -> fail
-        ok = perf_gate.gate({"a": 0.0}, {"a": 1.0}, max_ratio=2.0,
-                            min_seconds=0.75, out=io.StringIO())
-        assert ok == []
-        bad = perf_gate.gate({"a": 0.0}, {"a": 2.0}, max_ratio=2.0,
-                             min_seconds=0.75, out=io.StringIO())
-        assert bad == ["a"]
-
-    def test_one_sided_sections_are_informational(self):
-        out = io.StringIO()
-        fails = perf_gate.gate({"gone": 5.0}, {"new": 50.0},
-                               max_ratio=2.0, min_seconds=0.75, out=out)
-        assert fails == []
-        text = out.getvalue()
-        assert "absent from current" in text and "no baseline" in text
-
-
-class TestCLI:
-    def _write(self, path, sections):
-        path.write_text(json.dumps(_record(sections)))
-
-    def test_end_to_end_pass_and_fail(self, tmp_path):
-        base, cur = tmp_path / "base.json", tmp_path / "cur.json"
-        self._write(base, {"a": 2.0})
-        self._write(cur, {"a": 2.5})
-        args = ["--baseline", str(base), "--current", str(cur),
-                "--config", "cfg"]
-        assert perf_gate.main(args) == 0
-        self._write(cur, {"a": 9.0})
-        assert perf_gate.main(args) == 1
-
-    def test_missing_config_bucket_errors(self, tmp_path):
-        base = tmp_path / "base.json"
-        self._write(base, {"a": 1.0})
-        with pytest.raises(SystemExit, match="no 'nope' bucket"):
-            perf_gate.main(["--baseline", str(base),
-                            "--current", str(base), "--config", "nope"])
-
-    def test_committed_record_has_the_gate_bucket(self):
-        """The committed baseline must stay consumable by the CI gate."""
-        sections = perf_gate.load_sections(perf_gate.DEFAULT_BASELINE,
-                                           perf_gate.DEFAULT_CONFIG)
-        assert "table3" in sections and "fig1" in sections
-
-
-def test_skip_excludes_sections(tmp_path):
-    base, cur = tmp_path / "base.json", tmp_path / "cur.json"
-    base.write_text(json.dumps(_record({"a": 1.0, "kern": 1.0})))
-    cur.write_text(json.dumps(_record({"a": 1.0, "kern": 50.0})))
-    args = ["--baseline", str(base), "--current", str(cur),
-            "--config", "cfg"]
-    assert perf_gate.main(args) == 1
-    assert perf_gate.main(args + ["--skip", "kern"]) == 0
-
-
-class TestSpeedNormalization:
-    def test_factor_scales_baseline(self):
-        # current machine 2x slower -> a 2x wall-clock increase is not a
-        # regression once normalized
-        fails = perf_gate.gate({"a": 4.0}, {"a": 8.5}, max_ratio=2.0,
-                               min_seconds=0.75, factor=2.0,
-                               out=io.StringIO())
-        assert fails == []
-        fails = perf_gate.gate({"a": 4.0}, {"a": 8.5}, max_ratio=2.0,
-                               min_seconds=0.75, factor=1.0,
-                               out=io.StringIO())
-        assert fails == ["a"]
-
-    def test_speed_factor_caps_and_defaults(self):
-        assert perf_gate.speed_factor(0.0, 1.0) == 1.0
-        assert perf_gate.speed_factor(1.0, 0.0) == 1.0
-        assert perf_gate.speed_factor(1.0, 2.0) == 2.0
-        assert perf_gate.speed_factor(1.0, 100.0) == 4.0   # capped
-        assert perf_gate.speed_factor(100.0, 1.0) == 0.25  # capped
-
-    def test_end_to_end_normalized(self, tmp_path):
-        def write(path, sec, cal):
-            rec = _record(sec)
-            rec["runs"]["cfg"]["meta"] = {"calibration_seconds": cal}
-            path.write_text(json.dumps(rec))
-
-        base, cur = tmp_path / "base.json", tmp_path / "cur.json"
-        write(base, {"a": 4.0}, 0.5)
-        write(cur, {"a": 10.0}, 1.25)  # 2.5x slower machine, same code
-        args = ["--baseline", str(base), "--current", str(cur),
-                "--config", "cfg"]
-        assert perf_gate.main(args) == 0
-        write(cur, {"a": 10.0}, 0.5)   # same machine speed: regression
-        assert perf_gate.main(args) == 1
-
-    def test_committed_record_carries_calibration(self):
-        _, cal = perf_gate._load_bucket(perf_gate.DEFAULT_BASELINE,
-                                        perf_gate.DEFAULT_CONFIG)
-        assert cal > 0.0
-
-
-class TestBaselineAlias:
-    """BENCH.json <-> BENCH_PR4.json: either spelling loads the record."""
-
-    RECORD = {"runs": {"cfg": {"sections": {"a": {"seconds": 1.0,
-                                                  "rows": 1}}}}}
-
-    def test_old_name_resolves_to_new_record(self, tmp_path, monkeypatch,
-                                             capsys):
-        monkeypatch.chdir(tmp_path)
-        (tmp_path / "BENCH.json").write_text(json.dumps(self.RECORD))
-        sections = perf_gate.load_sections("BENCH_PR4.json", "cfg")
-        assert sections == {"a": 1.0}
-        assert "renamed baseline" in capsys.readouterr().err
-
-    def test_new_name_resolves_to_old_record(self, tmp_path, monkeypatch):
-        monkeypatch.chdir(tmp_path)
-        (tmp_path / "BENCH_PR4.json").write_text(json.dumps(self.RECORD))
-        assert perf_gate.load_sections("BENCH.json", "cfg") == {"a": 1.0}
-
-    def test_both_names_missing_is_a_clear_error(self, tmp_path,
-                                                 monkeypatch):
-        monkeypatch.chdir(tmp_path)
-        with pytest.raises(SystemExit, match="nor its former name"):
-            perf_gate.load_sections("BENCH.json", "cfg")
-
-    def test_unaliased_path_raises_file_not_found(self, tmp_path):
-        with pytest.raises(FileNotFoundError):
-            perf_gate.load_sections(str(tmp_path / "other.json"), "cfg")
 
 
 class TestObsGate:
@@ -235,20 +90,66 @@ class TestObsGate:
     def test_cli_flag_validation(self, tmp_path):
         with pytest.raises(SystemExit):  # obs flags need --obs-trace
             perf_gate.main(["--obs-require", "a==0"])
-        with pytest.raises(SystemExit):  # nothing to gate at all
+        with pytest.raises(SystemExit):  # no --obs-trace
             perf_gate.main([])
 
-    def test_cli_wall_and_obs_gates_combine(self, tmp_path):
-        base, cur = tmp_path / "base.json", tmp_path / "cur.json"
-        base.write_text(json.dumps(_record({"a": 2.0})))
-        cur.write_text(json.dumps(_record({"a": 2.5})))
-        trace = tmp_path / "t.jsonl"
-        trace.write_text(json.dumps(
-            {"ev": "counters", "pid": 1, "ts": 0,
-             "counters": {"store.recall.cold": 1}}) + "\n")
-        ok = ["--baseline", str(base), "--current", str(cur),
-              "--config", "cfg", "--obs-trace", str(trace),
-              "--obs-require", "store.recall.cold<=1"]
-        assert perf_gate.main(ok) == 0
-        bad = ok[:-1] + ["store.recall.cold==0"]
-        assert perf_gate.main(bad) == 1
+
+# --------------------------------------------------------------------------
+# Every gate expression the CI workflow passes, on synthetic traces
+# --------------------------------------------------------------------------
+_ROOT = pathlib.Path(__file__).resolve().parents[1]
+_CI_GATES = [(flag, quoted or bare) for flag, quoted, bare in re.findall(
+    r"--obs-(require|min-coverage)\s+(?:'([^']*)'|(\S+))",
+    (_ROOT / ".github" / "workflows" / "ci.yml").read_text())]
+_SRC = "\n".join(p.read_text()
+                 for p in (_ROOT / "src" / "repro").rglob("*.py"))
+
+# (holding, breaking) left-hand value for a right-hand value r
+_LHS = {"==": (0, 1), "!=": (1, 0), "<=": (0, 1), ">=": (0, -1),
+        "<": (-1, 0), ">": (1, 0)}
+
+
+def _synthetic_trace(flag: str, expr: str, holds: bool) -> tuple[list, list]:
+    """Events that satisfy (``holds``) or break one CI gate expression,
+    and the counter/span names it reads."""
+    if flag == "require":
+        lhs, op, rhs = perf_gate.parse_require(expr)
+        try:
+            r, names = float(rhs), [lhs]
+        except ValueError:
+            r, names = 5.0, [lhs, rhs]
+        counters = {lhs: r + _LHS[op][0 if holds else 1]}
+        if len(names) == 2:
+            counters[rhs] = r
+        return [{"ev": "counters", "pid": 1, "ts": 0,
+                 "counters": counters}], names
+    spans, _, frac = expr.partition("=")
+    names = spans.split("+")
+    wall_us = 10_000_000
+    covered = wall_us * (float(frac) + (0.05 if holds else -0.05))
+    events = [{"ev": "span", "name": "gate.wall", "pid": 1, "tid": 1,
+               "ts": 0, "dur": wall_us}]
+    for i, name in enumerate(names):
+        dur = covered / len(names)
+        events.append({"ev": "span", "name": name, "pid": 1, "tid": 2,
+                       "ts": i * dur, "dur": dur})
+    return events, names
+
+
+def test_ci_workflow_passes_gate_expressions():
+    assert {f for f, _ in _CI_GATES} == {"require", "min-coverage"}
+
+
+@pytest.mark.parametrize("holds", [True, False], ids=["holds", "violated"])
+@pytest.mark.parametrize("flag,expr", _CI_GATES,
+                         ids=[e for _, e in _CI_GATES])
+def test_ci_gate_expression(flag, expr, holds, tmp_path, capsys):
+    events, names = _synthetic_trace(flag, expr, holds)
+    for name in names:  # a misspelt name in the workflow fails here
+        assert f'"{name}"' in _SRC, f"{name!r} is emitted nowhere in src"
+    trace = tmp_path / "t.jsonl"
+    trace.write_text("".join(json.dumps(e) + "\n" for e in events))
+    rc = perf_gate.main(["--obs-trace", str(trace), f"--obs-{flag}", expr])
+    out = capsys.readouterr().out
+    assert rc == (0 if holds else 1)
+    assert out.rstrip().endswith("ok" if holds else "VIOLATED")
