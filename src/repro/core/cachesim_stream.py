@@ -17,13 +17,16 @@ How the passes fit together, per cache level:
 
 1. **Collapse + previous-occurrence, block by block.**  Consecutive
    duplicates collapse with the last line carried across block
-   boundaries.  Each block's previous-occurrence array is resolved
-   in-block by the same packed (line, time) sort the in-memory profile
-   uses, then block-cold refs consult a persistent sorted
-   ``line -> last collapsed index`` table (two ``O(distinct)`` arrays,
-   merged per block).  The per-block ``(line, prev)`` partials are kept
-   in a spill-aware block store (:class:`_Blocks`) that writes past-
-   budget blocks to a temporary directory.
+   boundaries.  Each block is sorted once by (line, time): a block of
+   mostly ascending runs by a stable, run-aware argsort of its lines,
+   any other by the in-memory profile's packed sort.  That order gives
+   the in-block previous-occurrence array; block-cold refs then consult
+   a persistent sorted ``line -> last collapsed index`` table (two
+   ``O(distinct)`` arrays), into which the same order's last ref of each
+   line is merged before the next block's lookups.  The per-block
+   ``(line, prev)`` partials are kept in a spill-aware block store
+   (:class:`_Blocks`) that writes past-budget blocks to a temporary
+   directory.
 2. **Stripe partition.**  Sets are grouped into contiguous *stripes*
    sized so one stripe's collapsed refs fit the chunk budget.  Same line
    -> same set -> same stripe, so every reuse window is stripe-local.
@@ -114,16 +117,36 @@ class _Blocks:
             self._tmp = None
 
 
-def _block_prev(cl: np.ndarray) -> np.ndarray:
-    """In-block previous-occurrence indices (-1 for block-cold refs) —
-    the in-memory profile's packed (line, time) sort, per block."""
+# Below this share of descents (``cl[i+1] < cl[i]``) a block sorts faster
+# by numpy's stable, run-aware argsort than by the packed introsort: the two
+# cross at about 1/32 on 2^20-line blocks of ascending runs at random bases.
+_RUN_SORT_MAX_DESCENTS = 1 / 32
+
+
+def _block_prev(cl: np.ndarray):
+    """In-block previous occurrences and the block's line-table update.
+
+    Returns ``(prev, lines_u, last_u)``: ``prev[i]`` is the index of the
+    previous ref to ``cl[i]``'s line in the block (-1 for block-cold
+    refs); ``lines_u`` the block's distinct lines, sorted, and ``last_u``
+    the in-block index of each one's last ref.  All three come from one
+    (line, time) sort of the block.  A block made mostly of ascending runs
+    (a walked window's) takes a stable, run-aware argsort of its lines,
+    counted as ``stream.block.run_sorted``; any other takes the in-memory
+    profile's packed sort.  Both give the same permutation.
+    """
     k = int(cl.size)
     if k == 0:
-        return np.zeros(0, dtype=np.int64)
+        empty = np.zeros(0, dtype=np.int64)
+        return empty, empty, empty
     shift = max(k - 1, 1).bit_length()
     cmin = int(cl.min())
-    cmax = int(cl.max())
-    if cmax - cmin < (1 << (62 - shift)):
+    span = int(cl.max()) - cmin
+    if np.count_nonzero(cl[1:] < cl[:-1]) < _RUN_SORT_MAX_DESCENTS * k:
+        obs.count("stream.block.run_sorted")
+        key = (cl - cmin).astype(np.uint32) if span < (1 << 32) else cl
+        order = np.argsort(key, kind="stable")
+    elif span < (1 << (62 - shift)):
         order = np.argsort(((cl - cmin) << shift)
                            | np.arange(k, dtype=np.int64))
     else:  # pragma: no cover - astronomically wide address range
@@ -132,28 +155,51 @@ def _block_prev(cl: np.ndarray) -> np.ndarray:
     same = sorted_cl[1:] == sorted_cl[:-1]
     prev = np.full(k, -1, dtype=np.int64)
     prev[order[1:][same]] = order[:-1][same]
-    return prev
+    ends = np.append(~same, True)  # each line's last ref in sorted order
+    return prev, sorted_cl[ends], order[ends]
 
 
 def _merge_table(tbl_lines: np.ndarray, tbl_gidx: np.ndarray,
                  lines_u: np.ndarray, gidx_u: np.ndarray):
-    """Merge a block's (sorted) line->last-gidx updates into the
-    persistent sorted table, keeping the newest gidx per line."""
+    """Merge a block's (sorted, unique) line->last-gidx updates into the
+    persistent sorted table, keeping the newest gidx per line.
+
+    Nothing is sorted: the smaller side's lines are located in the larger
+    by ``searchsorted`` and its new lines inserted at their positions; a
+    line on both sides keeps the update's gidx.
+    """
     if not tbl_lines.size:
         return lines_u, gidx_u
-    lines = np.concatenate([tbl_lines, lines_u])
-    gidx = np.concatenate([tbl_gidx, gidx_u])
-    order = np.argsort(lines, kind="stable")  # table first, updates after
-    lines = lines[order]
-    gidx = gidx[order]
-    last = np.ones(lines.size, dtype=bool)
-    last[:-1] = lines[1:] != lines[:-1]       # keep last (newest) per line
-    return lines[last], gidx[last]
+    update_smaller = lines_u.size < tbl_lines.size
+    if update_smaller:
+        big_l, big_g, small_l, small_g = tbl_lines, tbl_gidx, lines_u, gidx_u
+    else:
+        big_l, big_g, small_l, small_g = lines_u, gidx_u, tbl_lines, tbl_gidx
+    pos = np.searchsorted(big_l, small_l)
+    new = big_l[np.minimum(pos, big_l.size - 1)] != small_l
+    if update_smaller and not new.all():
+        big_g = big_g.copy()
+        big_g[pos[~new]] = small_g[~new]
+    at = pos[new]
+    if not at.size:
+        return big_l, big_g
+    at += np.arange(at.size)               # positions in the merged table
+    from_big = np.ones(big_l.size + at.size, dtype=bool)
+    from_big[at] = False
+    lines = np.empty(from_big.size, dtype=np.int64)
+    gidx = np.empty(from_big.size, dtype=np.int64)
+    lines[at] = small_l[new]
+    lines[from_big] = big_l
+    gidx[at] = small_g[new]
+    gidx[from_big] = big_g
+    return lines, gidx
 
 
 def _stripes_for(set_counts: np.ndarray, chunk: int) -> np.ndarray:
     """Contiguous set->stripe partition with ~``chunk`` collapsed refs
-    per stripe (a single hot set always gets its own stripe)."""
+    per stripe (a single hot set always gets its own stripe).  The ids
+    come in the narrowest unsigned dtype that holds them, so routing's
+    stable argsort by stripe is numpy's radix sort."""
     stripe_of_set = np.zeros(set_counts.size, dtype=np.int64)
     sid = 0
     acc = 0
@@ -164,6 +210,9 @@ def _stripes_for(set_counts: np.ndarray, chunk: int) -> np.ndarray:
             acc = 0
         stripe_of_set[s] = sid
         acc += c
+    for dtype in (np.uint8, np.uint16):
+        if sid <= np.iinfo(dtype).max:
+            return stripe_of_set.astype(dtype)
     return stripe_of_set
 
 
@@ -180,6 +229,9 @@ def _replay_level_chunked(blocks, sets: int, ways: int, *, chunk: int,
         collapsed = _Blocks(spill, tag=f"lvl{sets}")
         tbl_lines = np.zeros(0, dtype=np.int64)
         tbl_gidx = np.zeros(0, dtype=np.int64)
+        # a block's table update is merged before the next block's
+        # lookups: the last block's, which nothing reads, never is
+        update = None
         set_counts = np.zeros(sets, dtype=np.int64)
         last_line: int | None = None
         n = 0
@@ -198,7 +250,10 @@ def _replay_level_chunked(blocks, sets: int, ways: int, *, chunk: int,
             k = int(cl.size)
             if not k:
                 continue
-            prev_in = _block_prev(cl)
+            if update is not None:
+                tbl_lines, tbl_gidx = _merge_table(tbl_lines, tbl_gidx,
+                                                   *update)
+            prev_in, lines_u, last_u = _block_prev(cl)
             prev_g = np.where(prev_in >= 0, prev_in + m, -1)
             bcold = np.flatnonzero(prev_in < 0)
             if bcold.size:
@@ -211,16 +266,10 @@ def _replay_level_chunked(blocks, sets: int, ways: int, *, chunk: int,
             cold = prev_g < 0
             distinct += int(cold.sum())
             set_counts += np.bincount(cl % sets, minlength=sets)
-            # newest occurrence per line in this block -> table update
-            order = np.argsort(cl, kind="stable")
-            sorted_cl = cl[order]
-            ends = np.ones(k, dtype=bool)
-            ends[:-1] = sorted_cl[1:] != sorted_cl[:-1]
-            tbl_lines, tbl_gidx = _merge_table(
-                tbl_lines, tbl_gidx, sorted_cl[ends], order[ends] + m)
+            update = (lines_u, last_u + m)
             collapsed.append(np.stack([cl, prev_g], axis=1))
             m += k
-        del tbl_lines, tbl_gidx
+        del tbl_lines, tbl_gidx, update
 
     # -- pass 2: route collapsed refs to set stripes ----------------------
     with obs.span("sim.chunked.route"):

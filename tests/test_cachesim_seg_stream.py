@@ -23,7 +23,7 @@ import numpy as np
 import pytest
 
 from repro import obs
-from repro.core import cachesim, cachesim_vec, tracegen
+from repro.core import cachesim, cachesim_stream, cachesim_vec, tracegen
 from repro.core.cachesim_stream import simulate_chunked
 from repro.core.tracegen import TraceSpec, Workload
 
@@ -252,6 +252,148 @@ class TestJaxScan:
         assert obs.counters().get("memo.miss", 0) == 1
         assert obs.counters().get("memo.hit", 0) == 0
         assert _counters(got) == _counters(want)
+
+
+# --------------------------------------------------------------------------
+# Block sorts: the run-aware and packed paths, radix-routed stripes
+# --------------------------------------------------------------------------
+def _runny_lines(n: int, seed: int = 0, far: int = 0) -> np.ndarray:
+    """A walked window's shape: ascending line runs (sweeps over a few
+    operands, re-read each round, so later rounds hit), with one descent
+    per run.  ``far`` moves every other operand that many lines up."""
+    rng = np.random.default_rng(seed)
+    bases = np.arange(6, dtype=np.int64) * 50_000 + far * (np.arange(6) % 2)
+    runs, total = [], 0
+    while total < n:
+        b = bases[rng.integers(0, bases.size)] + rng.integers(0, 2_000)
+        run = np.arange(b, b + rng.integers(200, 1_500), dtype=np.int64)
+        runs.append(run)
+        total += run.size
+    return np.concatenate(runs)[:n]
+
+
+_SORT_CASES = {
+    # every block of a walked stream takes the run-aware sort
+    "runny": dict(lines=lambda: _runny_lines(40_000), sets=64, ways=8,
+                  chunk=1_024, run_sorted="all"),
+    # a shuffled stream keeps the packed sort
+    "shuffled": dict(
+        lines=lambda: np.random.default_rng(1).permutation(
+            _runny_lines(40_000)),
+        sets=64, ways=8, chunk=1_024, run_sorted="none"),
+    # more than 256 stripes of 16 refs: uint16 stripe ids
+    "uint16_stripes": dict(lines=lambda: _runny_lines(40_000, seed=2),
+                           sets=4_096, ways=4, chunk=16, run_sorted="all",
+                           stripe_dtype=np.uint16),
+    # a block's lines span more than 32 bits: the int64 run-aware sort
+    "wide_range": dict(lines=lambda: _runny_lines(40_000, far=1 << 34),
+                       sets=64, ways=8, chunk=1_024, run_sorted="all",
+                       wide=True),
+}
+
+
+class TestBlockSorts:
+    @pytest.mark.parametrize("case", sorted(_SORT_CASES))
+    def test_level_identical_to_in_memory(self, case, monkeypatch):
+        """``_replay_level_chunked`` gives the in-memory level's counters
+        and miss stream on every block shape, through whichever sort and
+        stripe-id dtype the shape selects.  Blocks are 4,096 lines;
+        ``chunk`` sizes the stripes."""
+        spec = _SORT_CASES[case]
+        lines = spec["lines"]()
+        sets, ways, chunk = spec["sets"], spec["ways"], spec["chunk"]
+        mask, distinct = cachesim_vec._replay_level(lines.copy(), sets, ways)
+
+        spans, dtypes = [], []
+        block_prev = cachesim_stream._block_prev
+        stripes_for = cachesim_stream._stripes_for
+
+        def spy_prev(cl):
+            spans.append(int(cl.max()) - int(cl.min()))
+            return block_prev(cl)
+
+        def spy_stripes(set_counts, chunk):
+            out = stripes_for(set_counts, chunk)
+            dtypes.append(out.dtype)
+            return out
+
+        monkeypatch.setattr(cachesim_stream, "_block_prev", spy_prev)
+        monkeypatch.setattr(cachesim_stream, "_stripes_for", spy_stripes)
+        obs.reset_counters()
+        blocks = (lines[lo:lo + 4_096] for lo in range(0, lines.size, 4_096))
+        hits, misses, miss_blocks, got_distinct, n = \
+            cachesim_stream._replay_level_chunked(
+                blocks, sets, ways, chunk=chunk, spill=1 << 20, scan=None)
+        try:
+            got_miss = np.concatenate(list(miss_blocks))
+        finally:
+            miss_blocks.close()
+        run_sorted = obs.counters().get("stream.block.run_sorted", 0)
+
+        assert (hits, misses, got_distinct, n) == \
+            (int(mask.sum()), int((~mask).sum()), distinct, lines.size)
+        np.testing.assert_array_equal(got_miss, lines[~mask])
+        assert run_sorted == {"all": len(spans), "none": 0}[
+            spec["run_sorted"]]
+        assert len(spans) > 1
+        if "stripe_dtype" in spec:
+            assert dtypes == [spec["stripe_dtype"]]
+        if spec.get("wide"):
+            assert max(spans) >= 1 << 32
+
+    @pytest.mark.parametrize("shape", ["walk_stream", "shuffled"])
+    def test_run_sorted_counts_walked_blocks(self, shape, monkeypatch):
+        """``stream.block.run_sorted`` counts every block of a walked
+        window, at every level, and no block of a shuffled one."""
+        pytest.importorskip("jax")
+        from repro.capture.zoo import get_capture
+
+        mc = get_capture("qwen2.5-14b", "decode", 8)
+        if shape == "walk_stream":
+            stream = mc.walk_stream(_STREAM_TARGET)
+        else:
+            stream = np.random.default_rng(3).permutation(
+                mc.walk_window(_STREAM_TARGET).addresses)
+        calls = []
+        block_prev = cachesim_stream._block_prev
+
+        def spy_prev(cl):
+            calls.append(cl.size)
+            return block_prev(cl)
+
+        monkeypatch.setattr(cachesim_stream, "_block_prev", spy_prev)
+        obs.reset_counters()
+        simulate_chunked(stream, cachesim.host_config(4), chunk=1 << 14)
+        run_sorted = obs.counters().get("stream.block.run_sorted", 0)
+        assert len(calls) >= 3  # one block or more at each of 3 levels
+        assert run_sorted == (len(calls) if shape == "walk_stream" else 0)
+
+    @pytest.mark.parametrize("table,update,seed,nested", [
+        (0, 500, 0, False), (1_000, 600, 1, False), (5_000, 50, 2, False),
+        (300, 3_000, 3, False), (2_000, 700, 4, True), (400, 1_500, 5, True)])
+    def test_merge_table_keeps_newest_gidx_per_line(self, table, update,
+                                                    seed, nested):
+        """The merged line table is sorted, one entry per line, holding
+        the update's gidx where a line is in both (a dict's last write).
+        ``nested``: the smaller side's lines all lie in the larger's."""
+        rng = np.random.default_rng(seed)
+        universe = np.arange(max(table, update) if nested
+                             else 4 * (table + update), dtype=np.int64)
+        tbl_lines = np.sort(rng.choice(universe, table, replace=False))
+        lines_u = np.sort(rng.choice(universe, update, replace=False))
+        tbl_gidx = rng.integers(0, 10_000, table)
+        gidx_u = rng.integers(10_000, 20_000, update)
+        want = dict(zip(tbl_lines.tolist(), tbl_gidx.tolist()))
+        want.update(zip(lines_u.tolist(), gidx_u.tolist()))
+        if nested:
+            assert len(want) == max(table, update)
+        elif table:  # lines in both, and lines new to the table
+            assert table < len(want) < table + update
+
+        lines, gidx = cachesim_stream._merge_table(
+            tbl_lines, tbl_gidx, lines_u, gidx_u)
+        assert lines.tolist() == sorted(want)
+        assert gidx.tolist() == [want[x] for x in sorted(want)]
 
 
 # --------------------------------------------------------------------------
